@@ -9,13 +9,13 @@ once with ``use_pallas_kernel=True`` — and reports µs/tick for both plus
 the ratio.  Each mode is compiled by a warm-up run first, so the numbers
 are steady-state execution, not trace+compile.
 
-Interpretation note: under ``REPRO_INTERPRET=1`` (the CPU-container
-default) the kernel body runs through the Pallas *interpreter*, which
+Interpretation note: on the CPU backend the kernel body runs through the
+Pallas *interpreter* (chosen automatically, `ops.resolve_interpret`), which
 emulates the TPU grid and is expected to be slower than the oracle — the
 suite is then a regression harness for the dispatch overhead and a
-correctness gate (``n_kernel_fallbacks == 0``).  On real TPUs
-(``REPRO_INTERPRET=0``) the same entry point measures the genuine fused
-speedup.  Results merge into results/benchmarks.json under
+correctness gate (``n_kernel_fallbacks == 0``), and its timings are not
+device numbers.  On a TPU the same entry point runs the kernel compiled
+and measures the genuine fused speedup.  Results merge into results/benchmarks.json under
 ``kernel_sweep`` (existing suites' entries survive — see
 `common.merge_results`).
 """
@@ -77,7 +77,7 @@ def run(job_counts=(2, 3)) -> tuple[dict, int]:
         "fused_us_per_tick": round(fused_us, 3),
         "fused_over_oracle": round(fused_us / max(oracle_us, 1e-9), 3),
         "kernel_fallbacks": fallbacks,
-        "interpret": kernel_ops.INTERPRET,
+        "interpret": kernel_ops.resolve_interpret(),
     }
     # each mode executed its plan twice (warm-up + timed) — report all the
     # ticks actually simulated so the harness's us/tick CSV stays honest

@@ -29,9 +29,11 @@ from benchmarks import (
     stragglers,
     timeline,
 )
+from repro import compile_cache
 
 
 def main() -> None:
+    compile_cache.enable()
     suites = [
         ("fig5_timeline", timeline.run),
         ("fig7_9_convergence", convergence.run),

@@ -179,7 +179,7 @@ rule("src/stale-pragma", WARNING,
 # --- kernel layer (the Pallas CC-tick kernel body; DESIGN.md §9) ----------
 rule("kernel/dyn-not-smem", ERROR,
      "DynamicParams operand is not an SMEM scalar vector",
-     "the protocol scalars must ride as an f32[NDYN] SMEM ref: a VMEM (or "
+     "the protocol scalars must ride as an f32[1, NDYN] SMEM ref: a VMEM (or "
      "missing) dyn operand means every grid step re-streams scalars "
      "through the vector path and the operand layout no longer matches "
      "ops.py's packing contract.")

@@ -82,7 +82,7 @@ def env_fingerprint() -> dict:
         "jax": jax.__version__,
         "repro_smoke": os.environ.get("REPRO_SMOKE", ""),
         "repro_full": os.environ.get("REPRO_FULL", ""),
-        "kernel_interpret": bool(ops.INTERPRET),
+        "kernel_interpret": ops.resolve_interpret(),
     }
 
 

@@ -15,9 +15,9 @@ the pallas_call kernel body) and checks, per compile group:
 * no host callbacks / debug prints in the hot path;
 * no non-whitelisted ``while``/``cond`` inside the tick-scan body.
 
-We lint at the jaxpr level rather than StableHLO on purpose: under
-``REPRO_INTERPRET=1`` the Pallas custom call never reaches HLO (interpret
-mode lowers to plain HLO ops), but the ``pallas_call`` primitive is always
+We lint at the jaxpr level rather than StableHLO on purpose: on the CPU
+backend (interpret mode, `ops.resolve_interpret`) the Pallas custom call
+never reaches HLO (interpret mode lowers to plain HLO ops), but the ``pallas_call`` primitive is always
 visible in the jaxpr, so the same proof holds on CPU CI and on device.
 """
 from __future__ import annotations
@@ -26,6 +26,7 @@ import dataclasses
 from typing import Iterable, Optional
 
 import jax
+from jax.extend import core as jax_core
 
 from repro.analysis.findings import Finding, make_finding
 
@@ -80,7 +81,7 @@ def _sub_jaxprs(params) -> Iterable:
         stack = [val]
         while stack:
             v = stack.pop()
-            if isinstance(v, (jax.core.ClosedJaxpr, jax.core.Jaxpr)):
+            if isinstance(v, (jax_core.ClosedJaxpr, jax_core.Jaxpr)):
                 yield v
             elif isinstance(v, (list, tuple)):
                 stack.extend(v)
@@ -94,7 +95,7 @@ def _aval_dtype(v) -> Optional[str]:
 
 def _walk(jaxpr, state: _WalkState, label: str, whitelist: frozenset,
           in_scan: bool, in_kernel: bool) -> None:
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jax_core.ClosedJaxpr):
         for c in jaxpr.consts:
             if str(getattr(c, "dtype", "")) == _F64:
                 state.f64_ops += 1

@@ -6,7 +6,7 @@ the config entitles it; this layer walks *into* that equation — its
 ``grid_mapping`` (operand memory spaces, block shapes, grid) and its body
 jaxpr — and checks the claims the perf story rests on:
 
-* the `DynamicParams` operand is an f32[NDYN] **SMEM** ref and the body
+* the `DynamicParams` operand is an f32[1, NDYN] **SMEM** ref and the body
   never writes it (``kernel/dyn-not-smem`` / ``kernel/dyn-written``);
 * every flow-state operand is a default/VMEM ref with the
   ``(min(SUBLANES, rows), LANES)`` block tile ops.py packs
@@ -25,10 +25,9 @@ The expectation comes from `kernels.ops.kernel_layout` — the same padding
 math the dispatch uses — and the kernel equation is located in the
 *already-traced* sweep jaxpr (`engine.trace_sweep` shares the jit cache),
 so the whole layer costs zero extra traces.  Under a vmapped sweep the
-pallas batching rule prepends batch dims: the grid gains leading axes,
-block shapes gain ``Mapped`` sentinels, and the kernel name becomes
-``_kernel_batched`` — `_normalize` strips all three so one expectation
-covers K=1 and K>1 programs.
+pallas batching rule prepends batch dims: the grid gains leading axes
+and blocks gain squeezed leading dims — `_normalize` and `_tile` strip
+both, so one expectation covers K=1 and K>1 programs.
 """
 from __future__ import annotations
 
@@ -36,6 +35,7 @@ import dataclasses
 from typing import Iterable, Optional
 
 import jax
+from jax.extend import core as jax_core
 
 from repro.analysis.findings import Finding, make_finding
 from repro.kernels import mltcp_step as ms
@@ -63,7 +63,7 @@ def _sub_jaxprs(params) -> Iterable:
         stack = [val]
         while stack:
             v = stack.pop()
-            if isinstance(v, (jax.core.ClosedJaxpr, jax.core.Jaxpr)):
+            if isinstance(v, (jax_core.ClosedJaxpr, jax_core.Jaxpr)):
                 yield v
             elif isinstance(v, (list, tuple)):
                 stack.extend(v)
@@ -71,26 +71,25 @@ def _sub_jaxprs(params) -> Iterable:
 
 def find_kernel_eqns(jaxpr) -> list:
     """Every CC-tick ``pallas_call`` eqn reachable from a (Closed)Jaxpr,
-    matched by kernel-body name prefix (`ms.KERNEL_NAME`; the batching
-    rule suffixes "_batched")."""
+    matched by the kernel body's function name (`ms.KERNEL_NAME`, read
+    from the body jaxpr's debug info; vmap keeps it)."""
     out = []
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jax_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            name = str(getattr(eqn.params.get("name_and_src_info"),
-                               "name", ""))
-            if name.startswith(ms.KERNEL_NAME):
+            debug = getattr(eqn.params["jaxpr"], "debug_info", None)
+            if getattr(debug, "func_name", None) == ms.KERNEL_NAME:
                 out.append(eqn)
         for sub in _sub_jaxprs(eqn.params):
             out.extend(find_kernel_eqns(sub))
     return out
 
 
-def _int_dims(block_shape) -> tuple:
-    """Block dims with batching sentinels (`Mapped`, None) stripped — the
-    per-grid-step tile shape."""
-    return tuple(d for d in block_shape if isinstance(d, int))
+def _tile(block_mapping) -> tuple:
+    """The per-grid-step tile shape: the block's ref shape, with the batch
+    dims a vmap prepends (squeezed out of the block) already gone."""
+    return tuple(block_mapping.transformed_block_aval.shape)
 
 
 def _space(block_mapping) -> str:
@@ -125,7 +124,7 @@ def _walk_body(jaxpr, watched: frozenset, state: dict, label: str,
     """Recurse the kernel body (and its pjit sub-jaxprs, threading which
     sub-invars alias the watched dyn ref) for f64 / gather-scatter /
     control-flow / dyn-write violations."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jax_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
@@ -151,7 +150,7 @@ def _walk_body(jaxpr, watched: frozenset, state: dict, label: str,
                 f"`{name}` inside the kernel body — the specialization "
                 f"is static, the body must be straight-line"))
         if (name == "swap" and eqn.invars
-                and isinstance(eqn.invars[0], jax.core.Var)
+                and isinstance(eqn.invars[0], jax_core.Var)
                 and eqn.invars[0] in watched):
             findings.append(make_finding(
                 "kernel/dyn-written", label,
@@ -159,11 +158,11 @@ def _walk_body(jaxpr, watched: frozenset, state: dict, label: str,
                 "(read-only by contract)"))
         if name == "pjit":
             sub = eqn.params.get("jaxpr")
-            if isinstance(sub, jax.core.ClosedJaxpr):
+            if isinstance(sub, jax_core.ClosedJaxpr):
                 # positional call: thread the watched-ref aliasing through
                 sub_watched = frozenset(
                     sv for ov, sv in zip(eqn.invars, sub.jaxpr.invars)
-                    if isinstance(ov, jax.core.Var) and ov in watched)
+                    if isinstance(ov, jax_core.Var) and ov in watched)
                 _walk_body(sub, sub_watched, state, label, findings)
         else:
             for sub in _sub_jaxprs(eqn.params):
@@ -194,14 +193,14 @@ def lint_kernel_eqn(eqn, expected: ms.KernelLayout, *, label: str,
     dyn_var = None
     if expected.dyn_index < len(n.in_mappings):
         dyn_bm = n.in_mappings[expected.dyn_index]
-        dyn_shape = _int_dims(dyn_bm.block_shape)
+        dyn_shape = _tile(dyn_bm)
         if _space(dyn_bm) != "smem" or dyn_shape != expected.dyn_shape:
             findings.append(make_finding(
                 "kernel/dyn-not-smem", label,
                 f"DynamicParams operand is {_space(dyn_bm)}{dyn_shape}, "
                 f"expected smem{expected.dyn_shape}"))
         else:
-            body = (n.body.jaxpr if isinstance(n.body, jax.core.ClosedJaxpr)
+            body = (n.body.jaxpr if isinstance(n.body, jax_core.ClosedJaxpr)
                     else n.body)
             dyn_var = body.invars[expected.dyn_index]
 
@@ -212,7 +211,7 @@ def lint_kernel_eqn(eqn, expected: ms.KernelLayout, *, label: str,
     for i, bm in state_mappings:
         if bm in n.in_mappings and i == expected.dyn_index:
             continue                       # the SMEM scalars, checked above
-        dims = _int_dims(bm.block_shape)
+        dims = _tile(bm)
         aval = bm.transformed_block_aval
         itemsize = getattr(getattr(aval, "dtype", None), "itemsize", 4)
         size = itemsize
@@ -250,7 +249,7 @@ def lint_kernel_eqn(eqn, expected: ms.KernelLayout, *, label: str,
     # --- body: straight-line elementwise f32, dyn read-only -------------
     state = {"f64": 0}
     watched = frozenset() if dyn_var is None else frozenset({dyn_var})
-    body_eqns = (n.body.jaxpr.eqns if isinstance(n.body, jax.core.ClosedJaxpr)
+    body_eqns = (n.body.jaxpr.eqns if isinstance(n.body, jax_core.ClosedJaxpr)
                  else n.body.eqns)
     _walk_body(n.body, watched, state, label, findings)
 

@@ -25,11 +25,24 @@ from repro.core.cc.types import CCParams, Feedback, FlowCCState
 Array = jnp.ndarray
 
 
+def cube_root(x: Array) -> Array:
+    """``x ** (1/3)`` for ``x >= 0``, as ``exp(log(x) / 3)``.
+
+    Mosaic (the Pallas TPU compiler) has no lowering for ``cbrt``, so the
+    fused kernel cannot use ``jnp.cbrt``; this form lowers on both paths.
+    The single source of truth for CUBIC's K: used by `_cubic_target`
+    below and inside the fused kernel body (`repro.kernels.mltcp_step`),
+    so kernel and oracle round identically.  ``x = 0`` (an unset
+    ``w_max``) gives exactly 0.
+    """
+    return jnp.where(x > 0.0, jnp.exp(jnp.log(x) * (1.0 / 3.0)), 0.0)
+
+
 def _cubic_target(params: CCParams, w_max: Array, t: Array) -> Array:
     c = params.cubic_c * params.cubic_scale
     # (1-beta)/c folds to one python-float constant (no constant-divisor
     # division in the graph — keeps kernel/oracle programs bit-identical)
-    k = jnp.cbrt(w_max * ((1.0 - params.cubic_beta) / c))
+    k = cube_root(w_max * ((1.0 - params.cubic_beta) / c))
     return c * (t - k) ** 3 + w_max
 
 

@@ -31,24 +31,6 @@ import jax.numpy as jnp
 
 Array = jnp.ndarray
 
-# jax 0.4.x ships lax.optimization_barrier without a vmap batching rule;
-# the rule is trivial (barrier each batched operand, keep the batch dims) and
-# upstream in newer releases.  Registered here because `ack_bytes` below must
-# work inside the vmapped sweep engine.  The private-module import is
-# guarded: on a jax whose internal layout moved, the rule is upstream
-# anyway and registration is simply skipped.
-try:
-    from jax._src.lax import lax as _lax_internal
-    from jax.interpreters import batching as _batching
-    _barrier_p = getattr(_lax_internal, "optimization_barrier_p", None)
-except ImportError:                                    # pragma: no cover
-    _barrier_p = None
-if _barrier_p is not None and _barrier_p not in _batching.primitive_batchers:
-    def _barrier_batcher(batched_args, batch_dims, **params):
-        return _barrier_p.bind(*batched_args, **params), batch_dims
-    _batching.primitive_batchers[_barrier_p] = _barrier_batcher
-
-
 def ack_bytes(num_acks: Array, mtu) -> Array:
     """``num_acks * mtu`` — the bytes acked this tick — as a materialized
     product.

@@ -13,14 +13,18 @@ elementwise, so blocks tile (8, 128) and the grid parallelizes over rows.
 Algorithm and MLTCP variant are *static* (one fabric runs one CC), so the
 kernel specializes at trace time with zero runtime branching — but the
 protocol *scalars* (DYN_FIELDS: F's slope/intercept, Algorithm 1's
-g/gamma/INIT_COMM_GAP) arrive as an f32[NDYN] SMEM operand, and the
+g/gamma/INIT_COMM_GAP) arrive as an f32[1, NDYN] SMEM operand, and the
 Static-baseline per-flow factors as an optional [R, 128] lanes operand, so
 traced sweep values (`simulate_sweep`'s vmapped K axis) keep the kernel
 fused instead of forcing a retrace or an oracle fallback (DESIGN.md §4).
 The SMEM ref is a plain operand rather than a `PrefetchScalarGridSpec`
 scalar-prefetch argument deliberately: the pallas batching rule lowers a
 *batched* prefetch operand to a serial `lax.scan` over the batch, which
-would run a K-point sweep one simulation at a time.
+would run a K-point sweep one simulation at a time.  It is 2-D because
+under vmap the batching rule prepends a squeezed batch dim to its block,
+and Mosaic accepts that only when the trailing two block dims equal the
+array's; a 1-D ``f32[NDYN]`` would leave a single trailing dim that is
+neither the full array nor (8, 128)-aligned.
 
 Oracle: repro.core.cc_tick (via ref.py) — the exact module the netsim
 engine uses — fuzz-tested field-by-field (including under traced
@@ -37,6 +41,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import iteration
+from repro.core.cc.cubic import cube_root
 from repro.core.cc.types import Algo, Variant
 
 LANES = 128
@@ -55,12 +60,12 @@ OUT_ORDER = list(DET_FIELDS) + list(CC_FIELDS) + ["stage", "ratio", "rate"]
 DYN_FIELDS = ("slope", "intercept", "g", "gamma", "init_comm_gap")
 NDYN = len(DYN_FIELDS)
 
-# The kernel body's name in traced programs (`name_and_src_info`); the
-# pallas batching rule appends "_batched" under vmap, so locate the
-# CC-tick pallas_call by prefix-matching this.  This is the static
-# analyzer's handle onto the body jaxpr (analysis/kernel_lint.py): the
-# body is reachable from the already-traced sweep jaxpr via the
-# pallas_call eqn's `jaxpr` param, so linting it costs zero extra traces.
+# The kernel body's function name in traced programs (the pallas_call
+# body jaxpr's debug info, kept under vmap): the CC-tick pallas_call is
+# located by matching this.  This is the static analyzer's handle onto
+# the body jaxpr (analysis/kernel_lint.py): the body is reachable from the
+# already-traced sweep jaxpr via the pallas_call eqn's `jaxpr` param, so
+# linting it costs zero extra traces.
 KERNEL_NAME = "_kernel"
 
 
@@ -79,7 +84,7 @@ class KernelLayout:
     n_inputs: int                   # dyn + optional factors + IN_ORDER
     n_outputs: int                  # OUT_ORDER
     dyn_index: int                  # position of the SMEM scalars operand
-    dyn_shape: tuple                # (NDYN,)
+    dyn_shape: tuple                # (1, NDYN)
     use_static_factors: bool
 
 
@@ -91,15 +96,15 @@ def expected_layout(rows: int, use_static_factors: bool = False
         rows=rows, block=block, grid=(rows // block[0],),
         n_inputs=1 + int(use_static_factors) + len(IN_ORDER),
         n_outputs=len(OUT_ORDER),
-        dyn_index=0, dyn_shape=(NDYN,),
+        dyn_index=0, dyn_shape=(1, NDYN),
         use_static_factors=use_static_factors)
 
 
 def _kernel(p, dyn_ref, *refs):
     # protocol scalars, read from SMEM (operand-carried — possibly traced
     # sweep values; see module docstring)
-    slope, intercept = dyn_ref[0], dyn_ref[1]
-    g, gamma, init_comm_gap = dyn_ref[2], dyn_ref[3], dyn_ref[4]
+    slope, intercept = dyn_ref[0, 0], dyn_ref[0, 1]
+    g, gamma, init_comm_gap = dyn_ref[0, 2], dyn_ref[0, 3], dyn_ref[0, 4]
     if p["use_static_factors"]:
         factors_r, refs = refs[0], refs[1:]
     n_in = len(IN_ORDER)
@@ -174,7 +179,7 @@ def _kernel(p, dyn_ref, *refs):
             c = p["cubic_c"] * p["cubic_scale"]
             tt = jnp.maximum(now - epoch_r[...], 0.0)
             # (1-beta)/c is a python-float constant, as in core.cc.cubic
-            kk = jnp.cbrt(w_max_r[...] * ((1.0 - p["cubic_beta"]) / c))
+            kk = cube_root(w_max_r[...] * ((1.0 - p["cubic_beta"]) / c))
             target = c * (f_wi * tt - kk) ** 3 + w_max_r[...]     # Eq. 9
             grow = acks * jnp.maximum(target - cwnd, 0.0) \
                 / jnp.maximum(cwnd, 1e-6)
@@ -247,7 +252,7 @@ def mltcp_tick_arrays(cfg_static: dict, dyn: jnp.ndarray, arrays: dict, *,
                       interpret: bool = True) -> dict:
     """Run the fused tick.
 
-    ``dyn``: f32[NDYN] protocol scalars per DYN_FIELDS, carried as an SMEM
+    ``dyn``: [NDYN] protocol scalars per DYN_FIELDS, carried as an SMEM
     operand (values may be traced — a sweep point — without retracing the
     kernel).  ``arrays``: {field: [R, 128]} per IN_ORDER ("stage" int32,
     rest f32); ``static_factors``: optional [R, 128] per-flow Static [67]
@@ -257,7 +262,7 @@ def mltcp_tick_arrays(cfg_static: dict, dyn: jnp.ndarray, arrays: dict, *,
     r = arrays["cwnd"].shape[0]
     block = (min(SUBLANES, r), LANES)
     spec = pl.BlockSpec(block, lambda i: (i, 0))
-    ins = [jnp.asarray(dyn, jnp.float32)]
+    ins = [jnp.asarray(dyn, jnp.float32).reshape(1, NDYN)]
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
     if static_factors is not None:
         ins.append(static_factors)

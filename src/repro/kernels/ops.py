@@ -14,16 +14,16 @@
                      fall back to the jnp oracle — loudly, via
                      ``FALLBACK_COUNT`` and a one-time warning.
 
-``INTERPRET`` defaults to the ``REPRO_INTERPRET`` env var (default "1"):
-this container is CPU-only, and interpret mode executes the kernel body
-exactly as the TPU grid would (the brief's validation mode).  On real TPUs
-run with ``REPRO_INTERPRET=0`` — or pass ``interpret=False`` per call; every
-wrapper takes an ``interpret`` override (None = module default).
+Interpret mode follows the platform (`resolve_interpret`): the CPU
+backend runs the kernel bodies through the Pallas interpreter, which
+executes them exactly as the TPU grid would (the tests' validation mode);
+the TPU backend always runs them compiled.  Every wrapper takes a per-call
+``interpret`` override (None = the platform's choice), which ahead-of-time
+compiles for a described TPU use from a CPU process.
 """
 from __future__ import annotations
 
 import functools
-import os
 import warnings
 from typing import Optional
 
@@ -39,19 +39,6 @@ from repro.kernels import rg_lru as rl
 
 Array = jnp.ndarray
 
-
-def _env_flag(name: str, default: bool) -> bool:
-    """Parse a boolean env var ("0"/"false"/"no"/"off" false, anything else
-    true); unset *or empty* means the default (a blank export is how shells
-    and CI yamls "clear" a variable, not a request for False)."""
-    raw = os.environ.get(name)
-    if raw is None or not raw.strip():
-        return default
-    return raw.strip().lower() not in ("0", "false", "no", "off")
-
-
-# CPU containers interpret; real TPUs run compiled (REPRO_INTERPRET=0).
-INTERPRET = _env_flag("REPRO_INTERPRET", True)
 
 # Incremented once per trace that routes mltcp_cc_tick through the jnp
 # oracle instead of the fused kernel (mirrors engine.TRACE_COUNT); tests pin
@@ -72,8 +59,26 @@ def reset_fallback_warnings() -> None:
     _FALLBACK_WARNED.clear()
 
 
-def _resolve_interpret(override: Optional[bool]) -> bool:
-    return INTERPRET if override is None else override
+_INTERPRET_BY_BACKEND = {"cpu": True, "tpu": False}
+
+
+def resolve_interpret(override: Optional[bool] = None) -> bool:
+    """Whether a kernel call runs through the Pallas interpreter.
+
+    ``override`` wins when given.  Otherwise the default backend decides:
+    the CPU interprets, the TPU compiles, and any other backend raises —
+    the kernels are written for the TPU, and silently interpreting them on
+    an accelerator would hide the device behind the interpreter.
+    """
+    if override is not None:
+        return override
+    backend = jax.default_backend()
+    try:
+        return _INTERPRET_BY_BACKEND[backend]
+    except KeyError:
+        raise RuntimeError(
+            f"no Pallas kernel path for backend {backend!r}: the kernels "
+            f"compile for the TPU and interpret on the CPU") from None
 
 
 # ---------------------------------------------------------------------------
@@ -110,7 +115,7 @@ def _flash_fwd_impl(q, k, v, causal, window, softcap, interpret=None):
     out = fa.flash_attention_fwd(
         qp, kp, vp, causal=causal, window=window, softcap=softcap,
         s_real=s, scale=1.0 / (q.shape[3] ** 0.5),
-        block_q=bq, block_k=bk, interpret=_resolve_interpret(interpret))
+        block_q=bq, block_k=bk, interpret=resolve_interpret(interpret))
     if pad_d:
         out = out[..., : q.shape[3]]
     if out.shape[1] != t:
@@ -145,7 +150,7 @@ def rg_lru(a: Array, b: Array, interpret: Optional[bool] = None) -> Array:
 def _rg_lru_impl(a, b, interpret=None):
     ap, pad = _pad_to(a, 2, rl.BLOCK_D)
     bp, _ = _pad_to(b, 2, rl.BLOCK_D)
-    out = rl.rg_lru_scan(ap, bp, interpret=_resolve_interpret(interpret))
+    out = rl.rg_lru_scan(ap, bp, interpret=resolve_interpret(interpret))
     return out[..., : a.shape[2]] if pad else out
 
 
@@ -208,7 +213,7 @@ def mltcp_cc_tick(cfg: core.MLTCPConfig, state: core.MLTCPState,
 
     The protocol scalars (``dyn``, default: the config's floats) and the
     Static-baseline per-flow ``static_factors`` travel into the kernel as
-    *operands* — an f32[NDYN] SMEM ref and an [R, 128] lanes ref — so
+    *operands* — an f32[1, NDYN] SMEM ref and an [R, 128] lanes ref — so
     traced sweep values (`simulate_sweep`'s vmapped K axis) run fused, one
     program per compile group.  Only structural options the kernel does not
     implement (non-default favoritism, non-linear F family) fall back to
@@ -332,7 +337,7 @@ def mltcp_cc_tick(cfg: core.MLTCPConfig, state: core.MLTCPState,
     factors = (None if static_factors is None
                else _pack(static_factors, n_pad, fill=1.0))
     out = ms.mltcp_tick_arrays(p, dyn_vec, arrays, static_factors=factors,
-                               interpret=_resolve_interpret(interpret))
+                               interpret=resolve_interpret(interpret))
 
     def unpack(x, dtype=jnp.float32):
         return x.reshape(-1)[:n].astype(dtype)

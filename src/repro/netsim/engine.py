@@ -780,8 +780,12 @@ def _tick(cfg: SimConfig, statics: TickStatics, sweep: SweepParams,
     ].add(fwd.reshape(-1))
     transit = transit.at[M].set(0.0)
 
-    # per-flow drop / mark signals
-    dropped_f = dropped.sum(axis=0)                              # [N] bytes
+    # per-flow drop / mark signals.  The barrier pins the flow vector as a
+    # materialized value: otherwise XLA may merge `dropped_f.sum()` below
+    # into one reduce over `dropped` in one program and not in another
+    # (the blackhole add sits between them), summing in a different order
+    # — armed-identity faults must stay bitwise equal to faults off.
+    dropped_f = jax.lax.optimization_barrier(dropped.sum(axis=0))  # [N] B
     if inj_lost is not None:
         dropped_f = dropped_f + inj_lost       # blackholed first-hop bytes
     marked_f = marked.sum(axis=0)
@@ -806,7 +810,13 @@ def _tick(cfg: SimConfig, statics: TickStatics, sweep: SweepParams,
     # 5. Byte accounting & comm-phase completion
     # ------------------------------------------------------------------
     to_deliver = jnp.maximum(to_deliver - delivered, 0.0)
-    flow_done = (to_deliver <= 0.5 * mss).astype(jnp.int32)
+    # float32 byte accounting drifts by ulps of the quota on every tick, so
+    # `to_deliver` can end a few bytes above the half-packet tolerance.  A
+    # flow with nothing left to send and nothing left in the network can
+    # deliver no more: it is done, or its job would wait forever.
+    in_network = backlog[:M].sum(axis=0) + transit[:M].sum(axis=0)  # [N]
+    drained = (to_send <= 0.0) & (in_network <= 0.5 * mss)
+    flow_done = ((to_deliver <= 0.5 * mss) | drained).astype(jnp.int32)
     job_all_done = jnp.ones((J,), jnp.int32).at[statics.f2j].min(flow_done) > 0
     comm_done = in_comm & job_all_done
 
@@ -992,12 +1002,38 @@ def _run_single(cfg: SimConfig, statics: TickStatics,
 TRACE_COUNT = 0
 
 
-@partial(jax.jit, static_argnums=(0,))
-def _run_sweep(cfg: SimConfig, sweep: SweepParams) -> RawSimOutput:
+@partial(jax.jit, static_argnums=(0, 2))
+def _run_sweep(cfg: SimConfig, sweep: SweepParams,
+               mesh: Optional[jax.sharding.Mesh]) -> RawSimOutput:
     global TRACE_COUNT
     TRACE_COUNT += 1
     statics = _build_statics(cfg)
-    return jax.vmap(lambda s: _run_single(cfg, statics, s))(sweep)
+    run = jax.vmap(lambda s: _run_single(cfg, statics, s))
+    if mesh is not None:
+        # Mosaic kernels cannot be partitioned automatically: each device
+        # runs the same vmapped program over its own K/n sweep points.
+        # check_vma is off because the kernel's pallas_call out_shapes carry
+        # no varying-mesh-axes annotation (every value varies along "k").
+        spec = jax.sharding.PartitionSpec(mesh.axis_names[0])
+        run = jax.shard_map(run, mesh=mesh, in_specs=spec, out_specs=spec,
+                            check_vma=False)
+    return run(sweep)
+
+
+def _sweep_mesh(sweep: SweepParams) -> Optional[jax.sharding.Mesh]:
+    """The 1-D device mesh the sweep's K axis is laid out over, if any.
+
+    `experiment._shard_sweep` commits every leaf to one NamedSharding over
+    a ``("k",)`` mesh; the sweep program then runs under `shard_map` on
+    that mesh.  Anything else (host arrays, one device) runs unsharded,
+    under the same jit cache key as any unsharded call.
+    """
+    leaf = jax.tree_util.tree_leaves(sweep)[0]
+    sharding = getattr(leaf, "sharding", None)
+    if (isinstance(sharding, jax.sharding.NamedSharding)
+            and sharding.mesh.size > 1):
+        return sharding.mesh
+    return None
 
 
 def _check_cfg(cfg: SimConfig) -> None:
@@ -1059,7 +1095,7 @@ def simulate_sweep(cfg: SimConfig, sweep: SweepParams) -> RawSimOutput:
     (topology, jobs, algorithm, K) changes — never per grid point.
     """
     _validate_sweep(cfg, sweep)
-    return _run_sweep(cfg, sweep)
+    return _run_sweep(cfg, sweep, _sweep_mesh(sweep))
 
 
 def lower_sweep(cfg: SimConfig, sweep: SweepParams):
@@ -1074,7 +1110,7 @@ def lower_sweep(cfg: SimConfig, sweep: SweepParams):
     for cold groups.
     """
     _validate_sweep(cfg, sweep)
-    return _run_sweep.lower(cfg, sweep)
+    return _run_sweep.lower(cfg, sweep, _sweep_mesh(sweep))
 
 
 def trace_sweep(cfg: SimConfig, sweep: SweepParams):
@@ -1088,7 +1124,7 @@ def trace_sweep(cfg: SimConfig, sweep: SweepParams):
     are proved about the real program, not a re-traced imitation.
     """
     _validate_sweep(cfg, sweep)
-    return _run_sweep.trace(cfg, sweep)
+    return _run_sweep.trace(cfg, sweep, _sweep_mesh(sweep))
 
 
 def simulate(cfg: SimConfig) -> RawSimOutput:
@@ -1100,5 +1136,5 @@ def simulate(cfg: SimConfig) -> RawSimOutput:
     on the config's concrete floats" path is gone; DESIGN.md §4).
     """
     _check_cfg(cfg)
-    raw = _run_sweep(cfg, make_sweep(cfg))
+    raw = _run_sweep(cfg, make_sweep(cfg), None)
     return jax.tree_util.tree_map(lambda x: x[0], raw)

@@ -97,7 +97,7 @@ def test_f64_upcast_in_scan_body_fires():
     def body(c, _):
         return c + jnp.float64(1.0), None
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda x: jax.lax.scan(body, x, None, length=3))(
                 jnp.zeros((), jnp.float64))

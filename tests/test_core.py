@@ -164,3 +164,20 @@ def test_off_variant_ignores_bytes_ratio():
     st2, _ = cc_tick(cfg, st, fb, jnp.full((2,), 1e6))
     # same acks, different bytes_ratio -> identical growth when OFF
     assert float(st2.cc.cwnd[0]) == float(st2.cc.cwnd[1])
+
+
+def test_cube_root_matches_cbrt_and_is_zero_at_zero():
+    """CUBIC's K uses exp(log(x)/3) (Mosaic has no cbrt lowering): exactly
+    0 at x=0, and within f32 rounding of cbrt over 1e-6..1e6.  The bound:
+    log's absolute error eps*|log x| <= 1.2e-7 * 13.8 becomes a relative
+    error of a third of that after exp, plus an ulp each for log and exp,
+    so under 1e-6."""
+    from repro.core.cc.cubic import cube_root
+
+    x = jnp.concatenate([jnp.zeros((1,)),
+                         jnp.logspace(-6, 6, 241, dtype=jnp.float32)])
+    got = np.asarray(cube_root(x))
+    assert got[0] == 0.0
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got[1:], np.cbrt(np.asarray(x[1:], np.float64)),
+                               rtol=1e-6)

@@ -193,8 +193,11 @@ def _iter_counts(cfg, overrides=None):
 
 
 def test_churn_freezes_and_resumes_a_job():
+    # seed 1: under the default (partitionable threefry) random stream the
+    # seed-3 baseline is the one seed of 0-7 whose survivor completes more
+    # iterations with its competitor present throughout (41 vs 38)
     spec = netsim.FaultSpec(n_events=3, churn=True)
-    cfg = _cfg(sim_time=0.6, faults=spec)
+    cfg = _cfg(sim_time=0.6, faults=spec, seed=1)
     base = _iter_counts(cfg)
     gone = netsim.fault_schedule(      # job 1 out for the middle third
         cfg, [netsim.job_departs(0.2, 1), netsim.job_arrives(0.4, 1)],

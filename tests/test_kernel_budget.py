@@ -34,23 +34,23 @@ def _rules(findings):
 # ---------------------------------------------------------------------------
 # Kernel-lint fixtures: a mini CC-tick-shaped pallas_call per violation.
 # The kernel fn is named `_kernel` so find_kernel_eqns prefix-matches it
-# exactly as it matches the real kernel (and its vmapped `_kernel_batched`).
+# exactly as it matches the real kernel (vmapped or not).
 # ---------------------------------------------------------------------------
 
 _LAYOUT = ms.KernelLayout(rows=ROWS, block=(ROWS, ms.LANES), grid=(1,),
                           n_inputs=3, n_outputs=1, dyn_index=0,
-                          dyn_shape=(NDYN,), use_static_factors=False)
+                          dyn_shape=(1, NDYN), use_static_factors=False)
 
 _STATE = pl.BlockSpec((ROWS, ms.LANES), lambda i: (i, 0))
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _kernel(dyn_ref, a_ref, b_ref, o_ref):
-    o_ref[...] = a_ref[...] * dyn_ref[0] + b_ref[...]
+    o_ref[...] = a_ref[...] * dyn_ref[0, 0] + b_ref[...]
 
 
 def _args():
-    return (jnp.zeros((NDYN,), jnp.float32),
+    return (jnp.zeros((1, NDYN), jnp.float32),
             jnp.zeros((ROWS, ms.LANES), jnp.float32),
             jnp.zeros((ROWS, ms.LANES), jnp.float32))
 
@@ -82,7 +82,7 @@ def test_fixture_kernel_is_clean():
 
 def test_dyn_not_smem_fires():
     # dyn rides as a full-array VMEM block instead of SMEM scalars
-    dyn_vmem = pl.BlockSpec((NDYN,), lambda i: (0,))
+    dyn_vmem = pl.BlockSpec((1, NDYN), lambda i: (0, 0))
     findings, _ = _lint(_call(in_specs=(dyn_vmem, _STATE, _STATE)))
     assert "kernel/dyn-not-smem" in _rules(findings)
 
@@ -90,7 +90,7 @@ def test_dyn_not_smem_fires():
 def test_state_not_vmem_fires():
     # a flow-state ref pinned to SMEM serializes the vector loads
     def _kernel(dyn_ref, a_ref, b_ref, o_ref):
-        o_ref[...] = b_ref[...] * dyn_ref[0] + a_ref[0, 0]
+        o_ref[...] = b_ref[...] * dyn_ref[0, 0] + a_ref[0, 0]
 
     findings, _ = _lint(_call(kernel=_kernel, in_specs=(_SMEM, _SMEM, _STATE)))
     assert "kernel/state-not-vmem" in _rules(findings)
@@ -108,7 +108,7 @@ def test_block_misaligned_and_grid_remainder_fire():
 def test_operand_mismatch_fires():
     wrong = ms.KernelLayout(rows=ROWS, block=(ROWS, ms.LANES), grid=(1,),
                             n_inputs=4, n_outputs=2, dyn_index=0,
-                            dyn_shape=(NDYN,), use_static_factors=True)
+                            dyn_shape=(1, NDYN), use_static_factors=True)
     findings, _ = _lint(_call(), layout=wrong)
     assert "kernel/operand-mismatch" in _rules(findings)
 
@@ -118,14 +118,14 @@ def test_f64_in_body_fires():
         v = a_ref[...].astype(jnp.float64) * 2.0
         o_ref[...] = v.astype(jnp.float32) + b_ref[...]
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         findings, _ = _lint(_call(kernel=_kernel))
     assert "kernel/f64-in-body" in _rules(findings)
 
 
 def test_gather_scatter_fires():
     def _kernel(dyn_ref, a_ref, b_ref, o_ref):
-        idx = dyn_ref[0].astype(jnp.int32) % (ROWS * ms.LANES)
+        idx = dyn_ref[0, 0].astype(jnp.int32) % (ROWS * ms.LANES)
         o_ref[...] = b_ref[...] + jnp.take(a_ref[...].ravel(), idx,
                                            mode="clip")
 
@@ -135,7 +135,7 @@ def test_gather_scatter_fires():
 
 def test_nested_control_fires():
     def _kernel(dyn_ref, a_ref, b_ref, o_ref):
-        o_ref[...] = jax.lax.cond(dyn_ref[0] > 0.0,
+        o_ref[...] = jax.lax.cond(dyn_ref[0, 0] > 0.0,
                                   lambda: a_ref[...] + b_ref[...],
                                   lambda: a_ref[...] - b_ref[...])
 
@@ -145,7 +145,7 @@ def test_nested_control_fires():
 
 def test_dyn_written_fires():
     def _kernel(dyn_ref, a_ref, b_ref, o_ref):
-        dyn_ref[0] = jnp.float32(1.0)
+        dyn_ref[0, 0] = jnp.float32(1.0)
         o_ref[...] = a_ref[...] + b_ref[...]
 
     findings, _ = _lint(_call(kernel=_kernel))
@@ -162,7 +162,7 @@ def test_grid_remainder_fires_on_uncoverable_rows():
     # covered exactly — the rule fires on the layout itself
     ragged = ms.KernelLayout(rows=12, block=(8, ms.LANES), grid=(1,),
                              n_inputs=3, n_outputs=1, dyn_index=0,
-                             dyn_shape=(NDYN,), use_static_factors=False)
+                             dyn_shape=(1, NDYN), use_static_factors=False)
     findings, _ = _lint(_call(), layout=ragged)
     assert "kernel/grid-remainder" in _rules(findings)
 
