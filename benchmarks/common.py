@@ -109,7 +109,7 @@ def plan(build, *, name: str = "", where=None, **axes) -> netsim.Plan:
 _PLAN_HEALTH = {"n_kernel_fallbacks": 0, "n_cache_hits": 0,
                 "n_compile_groups": 0, "n_groups_predicted": 0,
                 "n_group_mispredicts": 0, "n_plan_findings": 0,
-                "n_group_errors": 0, "n_budget_mismatches": 0}
+                "n_group_errors": 0}
 
 
 def reset_plan_health() -> None:
@@ -119,23 +119,6 @@ def reset_plan_health() -> None:
 
 def plan_health() -> dict:
     return dict(_PLAN_HEALTH)
-
-
-def _budget_mismatches(pr: netsim.PlanResult) -> int:
-    """Measured-vs-predicted cost cross-check: every profiled group whose
-    envelope (`GroupProfile.cost_envelope`, only filled under
-    ``profile=True``) matches *no* recorded budget of the same structural
-    signature.  Groups without an envelope, without a same-signature
-    baseline, or under a mismatched env are skipped, not counted."""
-    measured = [g for g in pr.profile.groups
-                if g.cost_envelope is not None and g.signature]
-    if not measured:
-        return 0
-    from repro.analysis.hlo_budget import BudgetBook
-
-    book = BudgetBook()
-    return sum(1 for g in measured
-               if book.matches_any(g.signature, g.cost_envelope) is False)
 
 
 def run_plan(p: netsim.Plan, **kw) -> netsim.PlanResult:
@@ -156,7 +139,6 @@ def run_plan(p: netsim.Plan, **kw) -> netsim.PlanResult:
     predicted = facts["groups"]
 
     pr = netsim.run_plan(p, **kw)
-    _PLAN_HEALTH["n_budget_mismatches"] += _budget_mismatches(pr)
     _PLAN_HEALTH["n_kernel_fallbacks"] += pr.n_kernel_fallbacks
     _PLAN_HEALTH["n_cache_hits"] += pr.n_cache_hits
     _PLAN_HEALTH["n_compile_groups"] += pr.n_compile_groups

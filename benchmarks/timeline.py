@@ -128,7 +128,7 @@ def make_plan(algos=("reno", "cubic", "dcqcn"), sockets=None) -> netsim.Plan:
 
 def run(algos=("reno", "cubic", "dcqcn"), sockets=None) -> tuple[dict, int]:
     pr = common.run_plan(make_plan(algos, sockets),
-                         telemetry=telemetry_spec(), profile=True)
+                         telemetry=telemetry_spec())
     out = {algo: _summarize(algo,
                             pr.select(algo=algo, variant="OFF"),
                             pr.select(algo=algo, variant="WI"))

@@ -306,36 +306,37 @@ def mltcp_cc_tick(cfg: core.MLTCPConfig, state: core.MLTCPState,
         "aggregate": aggregate,
     }
 
-    d, c = state.det, state.cc
-    now_arr = jnp.broadcast_to(jnp.asarray(fb.now, jnp.float32), (n,))
-    arrays = {
-        "bytes_sent": _pack(d.bytes_sent, n_pad),
-        "prev_ack_tstamp": _pack(d.prev_ack_tstamp, n_pad),
-        "iter_gap": _pack(d.iter_gap, n_pad, fill=1.0),
-        "max_gap": _pack(d.max_gap, n_pad, fill=1.0),
-        "cwnd": _pack(c.cwnd, n_pad, fill=1.0),
-        "ssthresh": _pack(c.ssthresh, n_pad, fill=1.0),
-        "cooldown": _pack(c.cooldown, n_pad),
-        "w_max": _pack(c.w_max, n_pad, fill=1.0),
-        "epoch_start": _pack(c.epoch_start, n_pad),
-        "rate_cur": _pack(c.rate_cur, n_pad, fill=cc.rate_min),
-        "rate_target": _pack(c.rate_target, n_pad, fill=cc.rate_min),
-        "alpha": _pack(c.alpha, n_pad),
-        "t_last_cnp": _pack(c.t_last_cnp, n_pad),
-        "t_last_inc": _pack(c.t_last_inc, n_pad),
-        "t_last_alpha": _pack(c.t_last_alpha, n_pad),
-        "stage": _pack(c.inc_stage, n_pad, dtype=jnp.int32),
-        "prev_ratio": _pack(d.bytes_ratio, n_pad),
-        "num_acks": _pack(fb.num_acks, n_pad),
-        "ack_bytes": _pack(ackb, n_pad),
-        "loss": _pack(fb.loss, n_pad),
-        "cnp": _pack(fb.cnp, n_pad),
-        "now": _pack(now_arr, n_pad),
-        "total_bytes": _pack(total_bytes, n_pad, fill=1.0),
-        "job_numer": _pack(job_numer, n_pad),
-    }
-    factors = (None if static_factors is None
-               else _pack(static_factors, n_pad, fill=1.0))
+    with jax.named_scope("cc.pack"):
+        d, c = state.det, state.cc
+        now_arr = jnp.broadcast_to(jnp.asarray(fb.now, jnp.float32), (n,))
+        arrays = {
+            "bytes_sent": _pack(d.bytes_sent, n_pad),
+            "prev_ack_tstamp": _pack(d.prev_ack_tstamp, n_pad),
+            "iter_gap": _pack(d.iter_gap, n_pad, fill=1.0),
+            "max_gap": _pack(d.max_gap, n_pad, fill=1.0),
+            "cwnd": _pack(c.cwnd, n_pad, fill=1.0),
+            "ssthresh": _pack(c.ssthresh, n_pad, fill=1.0),
+            "cooldown": _pack(c.cooldown, n_pad),
+            "w_max": _pack(c.w_max, n_pad, fill=1.0),
+            "epoch_start": _pack(c.epoch_start, n_pad),
+            "rate_cur": _pack(c.rate_cur, n_pad, fill=cc.rate_min),
+            "rate_target": _pack(c.rate_target, n_pad, fill=cc.rate_min),
+            "alpha": _pack(c.alpha, n_pad),
+            "t_last_cnp": _pack(c.t_last_cnp, n_pad),
+            "t_last_inc": _pack(c.t_last_inc, n_pad),
+            "t_last_alpha": _pack(c.t_last_alpha, n_pad),
+            "stage": _pack(c.inc_stage, n_pad, dtype=jnp.int32),
+            "prev_ratio": _pack(d.bytes_ratio, n_pad),
+            "num_acks": _pack(fb.num_acks, n_pad),
+            "ack_bytes": _pack(ackb, n_pad),
+            "loss": _pack(fb.loss, n_pad),
+            "cnp": _pack(fb.cnp, n_pad),
+            "now": _pack(now_arr, n_pad),
+            "total_bytes": _pack(total_bytes, n_pad, fill=1.0),
+            "job_numer": _pack(job_numer, n_pad),
+        }
+        factors = (None if static_factors is None
+                   else _pack(static_factors, n_pad, fill=1.0))
     out = ms.mltcp_tick_arrays(p, dyn_vec, arrays, static_factors=factors,
                                interpret=resolve_interpret(interpret))
 
@@ -347,28 +348,29 @@ def mltcp_cc_tick(cfg: core.MLTCPConfig, state: core.MLTCPState,
     boundary = iteration.boundary_mask(d.prev_ack_tstamp, d.iter_gap, dyn.g,
                                        fb.num_acks, fb.now)
 
-    det = core.MLTCPState(
-        cc=state.cc, det=state.det).det._replace(
-        bytes_sent=unpack(out["bytes_sent"]),
-        bytes_ratio=unpack(out["ratio"]),
-        prev_ack_tstamp=unpack(out["prev_ack_tstamp"]),
-        iter_gap=unpack(out["iter_gap"]),
-        max_gap=unpack(out["max_gap"]),
-        n_boundaries=d.n_boundaries + boundary.astype(jnp.int32),
-    )
-    ccs = state.cc._replace(
-        cwnd=unpack(out["cwnd"]),
-        ssthresh=unpack(out["ssthresh"]),
-        cooldown=unpack(out["cooldown"]),
-        w_max=unpack(out["w_max"]),
-        epoch_start=unpack(out["epoch_start"]),
-        rate_cur=unpack(out["rate_cur"]),
-        rate_target=unpack(out["rate_target"]),
-        alpha=unpack(out["alpha"]),
-        t_last_cnp=unpack(out["t_last_cnp"]),
-        t_last_inc=unpack(out["t_last_inc"]),
-        t_last_alpha=unpack(out["t_last_alpha"]),
-        inc_stage=unpack(out["stage"], jnp.int32),
-    )
-    rate = unpack(out["rate"])
+    with jax.named_scope("cc.unpack"):
+        det = core.MLTCPState(
+            cc=state.cc, det=state.det).det._replace(
+            bytes_sent=unpack(out["bytes_sent"]),
+            bytes_ratio=unpack(out["ratio"]),
+            prev_ack_tstamp=unpack(out["prev_ack_tstamp"]),
+            iter_gap=unpack(out["iter_gap"]),
+            max_gap=unpack(out["max_gap"]),
+            n_boundaries=d.n_boundaries + boundary.astype(jnp.int32),
+        )
+        ccs = state.cc._replace(
+            cwnd=unpack(out["cwnd"]),
+            ssthresh=unpack(out["ssthresh"]),
+            cooldown=unpack(out["cooldown"]),
+            w_max=unpack(out["w_max"]),
+            epoch_start=unpack(out["epoch_start"]),
+            rate_cur=unpack(out["rate_cur"]),
+            rate_target=unpack(out["rate_target"]),
+            alpha=unpack(out["alpha"]),
+            t_last_cnp=unpack(out["t_last_cnp"]),
+            t_last_inc=unpack(out["t_last_inc"]),
+            t_last_alpha=unpack(out["t_last_alpha"]),
+            inc_stage=unpack(out["stage"], jnp.int32),
+        )
+        rate = unpack(out["rate"])
     return core.MLTCPState(cc=ccs, det=det), rate

@@ -20,14 +20,96 @@ surface:
 ``with`` block exits, so reading them post-exit sees everything the block
 did).  Reading never imports ``repro.kernels`` — a plan that never enables
 ``use_pallas_kernel`` shouldn't pay the kernel import.
+
+Two more pieces measure where time goes, on the profiler's clock:
+
+* ``jit_seconds()`` — process totals of the seconds JAX spent tracing
+  (``trace``), lowering to MLIR (``lower``), compiling in XLA
+  (``compile``) and loading executables from the persistent compilation
+  cache (``cache_load``), from one `jax.monitoring` listener installed at
+  import.  ``CounterWatch.jit_s`` holds the same four as deltas.
+* ``span(name, **args)`` — a `jax.profiler.TraceAnnotation` (a host span
+  on the device trace's clock when the profiler runs, a no-op otherwise)
+  that also times its block with ``perf_counter`` for the caller:
+
+      with counters.span("run_plan.device", group=0) as sp:
+          ...
+      sp.seconds, sp.jit_s
+
+  Each finished span also goes to a log of the last ``SPAN_LOG``
+  (`recent_spans`), for a reader that does not hold the caller's result:
+  a harness that times `run_plan` calls from outside reads their phases
+  and their jit seconds there after the fact.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
 import sys
+import threading
+import time
 
-__all__ = ["traces", "fallbacks", "reset_fallback_warnings",
-           "watch", "CounterWatch"]
+import jax
+
+__all__ = ["traces", "fallbacks", "reset_fallback_warnings", "jit_seconds",
+           "span", "recent_spans", "watch", "CounterWatch"]
+
+# jax.monitoring events -> jit_seconds() kinds.  Trace, lower and compile
+# come as time spans; a jit traced inside another's trace records a span
+# nested in the outer one, which is counted once, as the outer's.  The
+# backend-compile span encloses the persistent-cache lookup, whose seconds
+# go to ``cache_load`` and not to ``compile``.
+_JIT_SPANS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+              "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+              "/jax/core/compile/backend_compile_duration": "compile"}
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+JIT_KINDS = ("trace", "lower", "compile", "cache_load")
+_jit_total = dict.fromkeys(JIT_KINDS, 0.0)
+_jit_lock = threading.Lock()
+_jit_local = threading.local()
+
+
+def _thread_state():
+    st = _jit_local
+    if not hasattr(st, "spans"):
+        # per kind, the (start, seconds counted) of finished spans that a
+        # later, enclosing span of the same kind may still swallow
+        st.spans = {kind: [] for kind in _JIT_SPANS.values()}
+        st.cache_load = 0.0      # cache loads inside the open compile span
+    return st
+
+
+def _on_span(event: str, start: float, end: float, **_) -> None:
+    kind = _JIT_SPANS.get(event)
+    if kind is None:
+        return
+    st = _thread_state()
+    seconds = end - start
+    if kind == "compile":
+        seconds -= st.cache_load
+        st.cache_load = 0.0
+    spans = st.spans[kind]
+    nested = 0.0
+    while spans and spans[-1][0] >= start:
+        nested += spans.pop()[1]
+    spans.append((start, seconds))
+    del spans[:-4096]
+    with _jit_lock:
+        _jit_total[kind] += seconds - nested
+
+
+def _on_duration(event: str, seconds: float, **_) -> None:
+    if event != _CACHE_LOAD:
+        return
+    _thread_state().cache_load += seconds
+    with _jit_lock:
+        _jit_total["cache_load"] += seconds
+
+
+# installed once, when this module is first imported
+jax.monitoring.register_event_time_span_listener(_on_span)
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def traces() -> int:
@@ -54,12 +136,66 @@ def reset_fallback_warnings() -> None:
         mod.reset_fallback_warnings()
 
 
+def jit_seconds() -> dict[str, float]:
+    """Seconds this process has spent in JAX's trace, lower, compile and
+    persistent-cache load, by kind (``JIT_KINDS``)."""
+    with _jit_lock:
+        return dict(_jit_total)
+
+
+# how many finished spans `recent_spans` keeps
+SPAN_LOG = 4096
+_span_log: collections.deque = collections.deque(maxlen=SPAN_LOG)
+_span_seq = itertools.count()
+
+
+class Span:
+    """One `span`: its ``name`` and ``args``; when its block exits,
+    ``seconds``, ``jit_s`` (the `jit_seconds()` deltas over the block) and
+    ``seq`` (the process's finished spans numbered from 0)."""
+
+    __slots__ = ("name", "args", "seconds", "jit_s", "seq")
+
+    def __init__(self, name: str, args: dict) -> None:
+        self.name, self.args = name, args
+        self.seconds = 0.0
+        self.jit_s = dict.fromkeys(JIT_KINDS, 0.0)
+        self.seq = -1
+
+
+@contextlib.contextmanager
+def span(name: str, **args):
+    """A named host span: a profiler `TraceAnnotation` carrying ``args``,
+    which also times the block; yields a `Span` that is filled in and
+    logged (`recent_spans`) when the block exits, normally or by an
+    exception."""
+    sp = Span(name, args)
+    with jax.profiler.TraceAnnotation(name, **args):
+        jit0 = jit_seconds()
+        t0 = time.perf_counter()
+        try:
+            yield sp
+        finally:
+            sp.seconds = time.perf_counter() - t0
+            jit1 = jit_seconds()
+            sp.jit_s = {k: jit1[k] - jit0[k] for k in JIT_KINDS}
+            sp.seq = next(_span_seq)
+            _span_log.append(sp)
+
+
+def recent_spans() -> list[Span]:
+    """The last ``SPAN_LOG`` finished spans of this process, oldest first;
+    the log is whole while the first one's ``seq`` is 0."""
+    return list(_span_log)
+
+
 class CounterWatch:
-    """Live deltas of both counters since construction."""
+    """Live deltas of the counters since construction."""
 
     def __init__(self) -> None:
         self._traces0 = traces()
         self._fallbacks0 = fallbacks()
+        self._jit0 = jit_seconds()
 
     @property
     def traces(self) -> int:
@@ -68,6 +204,12 @@ class CounterWatch:
     @property
     def fallbacks(self) -> int:
         return fallbacks() - self._fallbacks0
+
+    @property
+    def jit_s(self) -> dict[str, float]:
+        """`jit_seconds()` deltas, by kind."""
+        now = jit_seconds()
+        return {k: now[k] - self._jit0[k] for k in JIT_KINDS}
 
 
 @contextlib.contextmanager

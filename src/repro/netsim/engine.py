@@ -646,295 +646,310 @@ def _tick(cfg: SimConfig, statics: TickStatics, sweep: SweepParams,
     mss = cfg.protocol.cc.mss
     arange_n = jnp.arange(N)
 
-    key, k_loss, k_cnp, k_strag, k_samt = jax.random.split(st.key, 5)
+    # each stage runs under a named scope: HLO op_name metadata only, by
+    # which a profiler trace's device ops are told apart per stage
+    with jax.named_scope("tick.rng"):
+        key, k_loss, k_cnp, k_strag, k_samt = jax.random.split(st.key, 5)
 
     # ------------------------------------------------------------------
     # 0. Fault-event gather (cfg.faults is None -> this block vanishes)
     # ------------------------------------------------------------------
-    fault_idx = None
-    if cfg.faults is not None:
-        # event rows are sorted by start tick; row e is in effect on
-        # [fault_tick[e], fault_tick[e+1]) and row 0 is the identity
-        # baseline at tick 0, so the current row is a rank over the tick
-        # column — one reduce + gather per tick, no control flow, and
-        # nothing reaches the CC-tick kernel (DESIGN.md §8)
-        fault_idx = jnp.clip(
-            jnp.sum((sweep.fault_tick <= st.tick).astype(jnp.int32)) - 1,
-            0, cfg.faults.n_events - 1)
+    with jax.named_scope("tick.faults"):
+        fault_idx = None
+        if cfg.faults is not None:
+            # event rows are sorted by start tick; row e is in effect on
+            # [fault_tick[e], fault_tick[e+1]) and row 0 is the identity
+            # baseline at tick 0, so the current row is a rank over the tick
+            # column — one reduce + gather per tick, no control flow, and
+            # nothing reaches the CC-tick kernel (DESIGN.md §8)
+            fault_idx = jnp.clip(
+                jnp.sum((sweep.fault_tick <= st.tick).astype(jnp.int32)) - 1,
+                0, cfg.faults.n_events - 1)
 
     # ------------------------------------------------------------------
     # 1. Job phase machine: compute countdown -> comm-phase entry
     # ------------------------------------------------------------------
-    started = t >= statics.start_offset
-    if sweep.job_active is not None:
-        # padded-jobs axis: masked-off jobs never start, so their flows
-        # stay inert (no injection, no iterations) for this sweep point
-        started = started & sweep.job_active
-    churn_row = None
-    if cfg.faults is not None and cfg.faults.churn:
-        # churn: a departed job's compute clock freezes (`started` gate)
-        # and its comm phase is force-exited below, so its flows stop
-        # injecting; on re-arrival the stale t_rem <= 0 re-enters the
-        # interrupted comm sub-phase with a fresh quota.  The identity
-        # row is all-True — `& True` is an exact no-op.
-        churn_row = sweep.fault_job_active[fault_idx]            # [J]
-        started = started & churn_row
-    t_rem = jnp.where(~st.in_comm & started, st.t_rem - dt, st.t_rem)
-    compute_done = ~st.in_comm & started & (t_rem <= 0.0)
+    with jax.named_scope("tick.phase"):
+        started = t >= statics.start_offset
+        if sweep.job_active is not None:
+            # padded-jobs axis: masked-off jobs never start, so their flows
+            # stay inert (no injection, no iterations) for this sweep point
+            started = started & sweep.job_active
+        churn_row = None
+        if cfg.faults is not None and cfg.faults.churn:
+            # churn: a departed job's compute clock freezes (`started` gate)
+            # and its comm phase is force-exited below, so its flows stop
+            # injecting; on re-arrival the stale t_rem <= 0 re-enters the
+            # interrupted comm sub-phase with a fresh quota.  The identity
+            # row is all-True — `& True` is an exact no-op.
+            churn_row = sweep.fault_job_active[fault_idx]            # [J]
+            started = started & churn_row
+        t_rem = jnp.where(~st.in_comm & started, st.t_rem - dt, st.t_rem)
+        compute_done = ~st.in_comm & started & (t_rem <= 0.0)
 
-    if sweep.cassini_period is not None:
-        # Cassini agent: comm may only start on its slot grid (+/- eps).
-        # The schedule is a traced per-job value; period <= 0 disables the
-        # agent for that job (value-identical to the no-Cassini program),
-        # so scheduled and unscheduled plan points share one compile group.
-        on = sweep.cassini_period > 0.0
-        per = jnp.maximum(sweep.cassini_period, 1e-6)
-        k = jnp.ceil((t - sweep.cassini_offset) / per)
-        next_slot = sweep.cassini_offset + k * per
-        near = jnp.abs(jnp.round((t - sweep.cassini_offset) / per) * per
-                       + sweep.cassini_offset - t) <= sweep.cassini_eps
-        hold = jnp.where(compute_done & on & ~near & (st.hold_until <= t),
-                         next_slot, st.hold_until)
-        enter_comm = compute_done & (~on | near | (t >= hold))
-        hold_until = hold
-    else:
-        enter_comm = compute_done
-        hold_until = st.hold_until
+        if sweep.cassini_period is not None:
+            # Cassini agent: comm may only start on its slot grid (+/- eps).
+            # The schedule is a traced per-job value; period <= 0 disables the
+            # agent for that job (value-identical to the no-Cassini program),
+            # so scheduled and unscheduled plan points share one compile group.
+            on = sweep.cassini_period > 0.0
+            per = jnp.maximum(sweep.cassini_period, 1e-6)
+            k = jnp.ceil((t - sweep.cassini_offset) / per)
+            next_slot = sweep.cassini_offset + k * per
+            near = jnp.abs(jnp.round((t - sweep.cassini_offset) / per) * per
+                           + sweep.cassini_offset - t) <= sweep.cassini_eps
+            hold = jnp.where(compute_done & on & ~near & (st.hold_until <= t),
+                             next_slot, st.hold_until)
+            enter_comm = compute_done & (~on | near | (t >= hold))
+            hold_until = hold
+        else:
+            enter_comm = compute_done
+            hold_until = st.hold_until
 
-    in_comm = st.in_comm | enter_comm
-    if churn_row is not None:
-        in_comm = in_comm & churn_row
+        in_comm = st.in_comm | enter_comm
+        if churn_row is not None:
+            in_comm = in_comm & churn_row
 
-    # flows of entering jobs pick up their sub-phase quota
-    phase_bytes_job = sweep.comm_bytes[jnp.arange(J), st.phase_idx]  # [J]
-    enter_f = enter_comm[statics.f2j]
-    quota_f = (phase_bytes_job[statics.f2j] * statics.spj_inv)
-    to_send = jnp.where(enter_f, quota_f, st.to_send)
-    to_deliver = jnp.where(enter_f, quota_f, st.to_deliver)
-    comm_start = jnp.where(enter_f, t, st.comm_start)
+        # flows of entering jobs pick up their sub-phase quota
+        phase_bytes_job = sweep.comm_bytes[jnp.arange(J), st.phase_idx]  # [J]
+        enter_f = enter_comm[statics.f2j]
+        quota_f = (phase_bytes_job[statics.f2j] * statics.spj_inv)
+        to_send = jnp.where(enter_f, quota_f, st.to_send)
+        to_deliver = jnp.where(enter_f, quota_f, st.to_deliver)
+        comm_start = jnp.where(enter_f, t, st.comm_start)
 
     # ------------------------------------------------------------------
     # 2. Injection at current CC rate
     # ------------------------------------------------------------------
-    rate = core.send_rate(cfg.protocol.cc, st.proto.cc)          # [N] bytes/s
-    active = in_comm[statics.f2j] & (to_send > 0.0)
-    inj = jnp.where(active, jnp.minimum(rate * dt, to_send), 0.0)
-    to_send = to_send - inj
-    inj_lost = None
-    if cfg.faults is not None and cfg.faults.blackholes:
-        # blackholed flows are null-routed at the first hop: injected
-        # bytes vanish as drops (folded into dropped_f below, so they
-        # loss-signal after the usual feedback delay and retransmit when
-        # the hole closes).  Identity row is all-False: inj - 0.0 exact.
-        bh_row = sweep.fault_blackhole[fault_idx]                # [N]
-        inj_lost = jnp.where(bh_row, inj, 0.0)
-        inj = inj - inj_lost
+    with jax.named_scope("tick.inject"):
+        rate = core.send_rate(cfg.protocol.cc, st.proto.cc)      # [N] bytes/s
+        active = in_comm[statics.f2j] & (to_send > 0.0)
+        inj = jnp.where(active, jnp.minimum(rate * dt, to_send), 0.0)
+        to_send = to_send - inj
+        inj_lost = None
+        if cfg.faults is not None and cfg.faults.blackholes:
+            # blackholed flows are null-routed at the first hop: injected
+            # bytes vanish as drops (folded into dropped_f below, so they
+            # loss-signal after the usual feedback delay and retransmit when
+            # the hole closes).  Identity row is all-False: inj - 0.0 exact.
+            bh_row = sweep.fault_blackhole[fault_idx]                # [N]
+            inj_lost = jnp.where(bh_row, inj, 0.0)
+            inj = inj - inj_lost
 
     # ------------------------------------------------------------------
     # 3. Links: enqueue (RED) -> serve -> route departures
     # ------------------------------------------------------------------
-    incoming = st.transit
-    incoming = incoming.at[statics.first_link, arange_n].add(inj)
-    incoming = incoming.at[M].set(0.0)                           # trash row
+    with jax.named_scope("tick.links"):
+        incoming = st.transit
+        incoming = incoming.at[statics.first_link, arange_n].add(inj)
+        incoming = incoming.at[M].set(0.0)                       # trash row
 
-    q_len = st.backlog[:M].sum(axis=1)                           # [M]
-    p_red = _red_prob(sweep, q_len)                              # [M]
-    p_full = jnp.concatenate([p_red, jnp.zeros((1,), p_red.dtype)])
-    # taildrop on buffer overflow (both modes)
-    overflow = jnp.concatenate([
-        (q_len >= cfg.buffer_bytes).astype(jnp.float32), jnp.zeros((1,))])
+        q_len = st.backlog[:M].sum(axis=1)                           # [M]
+        p_red = _red_prob(sweep, q_len)                              # [M]
+        p_full = jnp.concatenate([p_red, jnp.zeros((1,), p_red.dtype)])
+        # taildrop on buffer overflow (both modes)
+        overflow = jnp.concatenate([
+            (q_len >= cfg.buffer_bytes).astype(jnp.float32), jnp.zeros((1,))])
 
-    if cfg.is_ecn():
-        marked = incoming * p_full[:, None]
-        drop_frac = overflow[:, None]
-    else:
-        marked = jnp.zeros_like(incoming)
-        drop_frac = jnp.minimum(p_full[:, None] + overflow[:, None], 1.0)
+        if cfg.is_ecn():
+            marked = incoming * p_full[:, None]
+            drop_frac = overflow[:, None]
+        else:
+            marked = jnp.zeros_like(incoming)
+            drop_frac = jnp.minimum(p_full[:, None] + overflow[:, None], 1.0)
 
-    dropped = incoming * drop_frac
-    kept = incoming - dropped
-    backlog = st.backlog + kept
+        dropped = incoming * drop_frac
+        kept = incoming - dropped
+        backlog = st.backlog + kept
 
-    tot = backlog[:M].sum(axis=1)
-    cap_eff = statics.cap
-    if cfg.faults is not None and cfg.faults.link_flaps:
-        # link flaps scale the *service* capacity only; acc_util keeps the
-        # nominal cap as its normalizer (utilization stays comparable
-        # across the flap, and scale=0.0 never divides by zero).  The
-        # identity row is all-ones: cap * 1.0 is bit-exact.
-        cap_eff = cap_eff * sweep.fault_link_scale[fault_idx]    # [M]
-    serve_ratio = jnp.where(tot > 0.0,
-                            jnp.minimum(1.0, cap_eff * dt / jnp.maximum(tot, 1e-9)),
-                            0.0)
-    serve_full = jnp.concatenate([serve_ratio, jnp.zeros((1,))])
-    dep = backlog * serve_full[:, None]
-    backlog = backlog - dep
-    backlog = backlog.at[M].set(0.0)
+        tot = backlog[:M].sum(axis=1)
+        cap_eff = statics.cap
+        if cfg.faults is not None and cfg.faults.link_flaps:
+            # link flaps scale the *service* capacity only; acc_util keeps the
+            # nominal cap as its normalizer (utilization stays comparable
+            # across the flap, and scale=0.0 never divides by zero).  The
+            # identity row is all-ones: cap * 1.0 is bit-exact.
+            cap_eff = cap_eff * sweep.fault_link_scale[fault_idx]    # [M]
+        serve_ratio = jnp.where(
+            tot > 0.0,
+            jnp.minimum(1.0, cap_eff * dt / jnp.maximum(tot, 1e-9)), 0.0)
+        serve_full = jnp.concatenate([serve_ratio, jnp.zeros((1,))])
+        dep = backlog * serve_full[:, None]
+        backlog = backlog - dep
+        backlog = backlog.at[M].set(0.0)
 
-    # route departures: next_link == M means delivered
-    is_final = statics.next_link == M                            # [M+1, N]
-    delivered = jnp.sum(dep * is_final, axis=0)                  # [N]
-    fwd = dep * (~is_final)
-    transit = jnp.zeros_like(st.transit).at[
-        statics.next_link.reshape(-1), jnp.tile(arange_n, M + 1)
-    ].add(fwd.reshape(-1))
-    transit = transit.at[M].set(0.0)
+        # route departures: next_link == M means delivered
+        is_final = statics.next_link == M                            # [M+1, N]
+        delivered = jnp.sum(dep * is_final, axis=0)                  # [N]
+        fwd = dep * (~is_final)
+        transit = jnp.zeros_like(st.transit).at[
+            statics.next_link.reshape(-1), jnp.tile(arange_n, M + 1)
+        ].add(fwd.reshape(-1))
+        transit = transit.at[M].set(0.0)
 
-    # per-flow drop / mark signals.  The barrier pins the flow vector as a
-    # materialized value: otherwise XLA may merge `dropped_f.sum()` below
-    # into one reduce over `dropped` in one program and not in another
-    # (the blackhole add sits between them), summing in a different order
-    # — armed-identity faults must stay bitwise equal to faults off.
-    dropped_f = jax.lax.optimization_barrier(dropped.sum(axis=0))  # [N] B
-    if inj_lost is not None:
-        dropped_f = dropped_f + inj_lost       # blackholed first-hop bytes
-    marked_f = marked.sum(axis=0)
-    loss_evt = _lane_uniform(k_loss, N) < -jnp.expm1(-dropped_f / mss)
-    cnp_evt = _lane_uniform(k_cnp, N) < -jnp.expm1(-marked_f / mss)
-    # dropped bytes must be retransmitted
-    to_send = to_send + dropped_f
+        # per-flow drop / mark signals.  The barrier pins the flow vector as a
+        # materialized value: otherwise XLA may merge `dropped_f.sum()` below
+        # into one reduce over `dropped` in one program and not in another
+        # (the blackhole add sits between them), summing in a different order
+        # — armed-identity faults must stay bitwise equal to faults off.
+        dropped_f = jax.lax.optimization_barrier(dropped.sum(axis=0))  # [N] B
+        if inj_lost is not None:
+            dropped_f = dropped_f + inj_lost       # blackholed first-hop bytes
+        marked_f = marked.sum(axis=0)
+        loss_evt = _lane_uniform(k_loss, N) < -jnp.expm1(-dropped_f / mss)
+        cnp_evt = _lane_uniform(k_cnp, N) < -jnp.expm1(-marked_f / mss)
+        # dropped bytes must be retransmitted
+        to_send = to_send + dropped_f
 
     # ------------------------------------------------------------------
     # 4. Feedback delay line (acks/loss/CNP arrive one RTT later)
     # ------------------------------------------------------------------
-    ptr = st.ring_ptr
-    fb_del = st.ring_del[ptr]
-    fb_loss = st.ring_loss[ptr]
-    fb_cnp = st.ring_cnp[ptr]
-    ring_del = st.ring_del.at[ptr].set(delivered)
-    ring_loss = st.ring_loss.at[ptr].set(loss_evt)
-    ring_cnp = st.ring_cnp.at[ptr].set(cnp_evt)
-    ring_ptr = (ptr + 1) % cfg.rtt_ticks
+    with jax.named_scope("tick.feedback"):
+        ptr = st.ring_ptr
+        fb_del = st.ring_del[ptr]
+        fb_loss = st.ring_loss[ptr]
+        fb_cnp = st.ring_cnp[ptr]
+        ring_del = st.ring_del.at[ptr].set(delivered)
+        ring_loss = st.ring_loss.at[ptr].set(loss_evt)
+        ring_cnp = st.ring_cnp.at[ptr].set(cnp_evt)
+        ring_ptr = (ptr + 1) % cfg.rtt_ticks
 
     # ------------------------------------------------------------------
     # 5. Byte accounting & comm-phase completion
     # ------------------------------------------------------------------
-    to_deliver = jnp.maximum(to_deliver - delivered, 0.0)
-    # float32 byte accounting drifts by ulps of the quota on every tick, so
-    # `to_deliver` can end a few bytes above the half-packet tolerance.  A
-    # flow with nothing left to send and nothing left in the network can
-    # deliver no more: it is done, or its job would wait forever.
-    in_network = backlog[:M].sum(axis=0) + transit[:M].sum(axis=0)  # [N]
-    drained = (to_send <= 0.0) & (in_network <= 0.5 * mss)
-    flow_done = ((to_deliver <= 0.5 * mss) | drained).astype(jnp.int32)
-    job_all_done = jnp.ones((J,), jnp.int32).at[statics.f2j].min(flow_done) > 0
-    comm_done = in_comm & job_all_done
+    with jax.named_scope("tick.accounting"):
+        to_deliver = jnp.maximum(to_deliver - delivered, 0.0)
+        # float32 byte accounting drifts by ulps of the quota on every tick, so
+        # `to_deliver` can end a few bytes above the half-packet tolerance.  A
+        # flow with nothing left to send and nothing left in the network can
+        # deliver no more: it is done, or its job would wait forever.
+        in_network = backlog[:M].sum(axis=0) + transit[:M].sum(axis=0)  # [N]
+        drained = (to_send <= 0.0) & (in_network <= 0.5 * mss)
+        flow_done = ((to_deliver <= 0.5 * mss) | drained).astype(jnp.int32)
+        job_all_done = jnp.ones((J,), jnp.int32).at[statics.f2j].min(
+            flow_done) > 0
+        comm_done = in_comm & job_all_done
 
-    last_phase = st.phase_idx >= (statics.n_phases - 1)
-    iter_done = comm_done & last_phase
-    phase_idx = jnp.where(comm_done, jnp.where(last_phase, 0, st.phase_idx + 1),
-                          st.phase_idx)
-    in_comm = in_comm & ~comm_done
+        last_phase = st.phase_idx >= (statics.n_phases - 1)
+        iter_done = comm_done & last_phase
+        phase_idx = jnp.where(comm_done,
+                              jnp.where(last_phase, 0, st.phase_idx + 1),
+                              st.phase_idx)
+        in_comm = in_comm & ~comm_done
 
-    # iteration bookkeeping + straggler sampling for the next iteration
-    iter_time = t - st.iter_start
-    iter_times = st.iter_times.at[
-        jnp.arange(J), jnp.minimum(st.iter_idx, cfg.max_iters_recorded - 1)
-    ].set(jnp.where(iter_done, iter_time,
-                    st.iter_times[jnp.arange(J),
-                                  jnp.minimum(st.iter_idx,
-                                              cfg.max_iters_recorded - 1)]))
-    iter_idx = st.iter_idx + iter_done.astype(jnp.int32)
-    iter_start = jnp.where(iter_done, t, st.iter_start)
+        # iteration bookkeeping + straggler sampling for the next iteration
+        iter_time = t - st.iter_start
+        iter_times = st.iter_times.at[
+            jnp.arange(J), jnp.minimum(st.iter_idx, cfg.max_iters_recorded - 1)
+        ].set(jnp.where(iter_done, iter_time,
+                        st.iter_times[jnp.arange(J),
+                                      jnp.minimum(st.iter_idx,
+                                                  cfg.max_iters_recorded - 1)]))
+        iter_idx = st.iter_idx + iter_done.astype(jnp.int32)
+        iter_start = jnp.where(iter_done, t, st.iter_start)
 
-    strag_p = sweep.straggle_prob
-    if cfg.faults is not None and cfg.faults.straggle_bursts:
-        # additive boost, clipped back to a probability; identity row is
-        # all-zeros (p + 0.0 and clip-to-[0,1] of a probability are exact)
-        strag_p = jnp.clip(strag_p + sweep.fault_straggle[fault_idx],
-                           0.0, 1.0)
-    straggles = _lane_uniform(k_strag, J) < strag_p
-    strag_amt = (0.05 + 0.05 * _lane_uniform(k_samt, J)) * sweep.iso_iter
-    straggle_extra = jnp.where(iter_done,
-                               jnp.where(straggles, strag_amt, 0.0),
-                               st.straggle_extra)
+        strag_p = sweep.straggle_prob
+        if cfg.faults is not None and cfg.faults.straggle_bursts:
+            # additive boost, clipped back to a probability; identity row is
+            # all-zeros (p + 0.0 and clip-to-[0,1] of a probability are exact)
+            strag_p = jnp.clip(strag_p + sweep.fault_straggle[fault_idx],
+                               0.0, 1.0)
+        straggles = _lane_uniform(k_strag, J) < strag_p
+        strag_amt = (0.05 + 0.05 * _lane_uniform(k_samt, J)) * sweep.iso_iter
+        straggle_extra = jnp.where(iter_done,
+                                   jnp.where(straggles, strag_amt, 0.0),
+                                   st.straggle_extra)
 
-    next_compute = sweep.compute[jnp.arange(J), phase_idx]
-    t_rem = jnp.where(comm_done,
-                      next_compute + jnp.where(iter_done, straggle_extra, 0.0),
-                      t_rem)
+        next_compute = sweep.compute[jnp.arange(J), phase_idx]
+        t_rem = jnp.where(
+            comm_done,
+            next_compute + jnp.where(iter_done, straggle_extra, 0.0), t_rem)
 
     # ------------------------------------------------------------------
     # 6. Protocol update (MLTCP / baselines) on delayed feedback
     # ------------------------------------------------------------------
-    fb = core.Feedback(num_acks=fb_del / mss, loss=fb_loss, cnp=fb_cnp, now=t)
-    flow_total = jnp.where(
-        jnp.asarray(cfg.protocol.aggregate_by_job),
-        wl.job_total_bytes[statics.f2j],
-        wl.job_total_bytes[statics.f2j] * statics.spj_inv)
-    comm_elapsed = jnp.clip((t - comm_start) / wl.period[statics.f2j],
-                            0.0, 1.0)
-    est_finish = jnp.clip(to_deliver / jnp.maximum(rate, 1.0)
-                          / wl.period[statics.f2j], 0.0, 1.0)
+    with jax.named_scope("tick.cc_update"):
+        fb = core.Feedback(num_acks=fb_del / mss, loss=fb_loss, cnp=fb_cnp,
+                           now=t)
+        flow_total = jnp.where(
+            jnp.asarray(cfg.protocol.aggregate_by_job),
+            wl.job_total_bytes[statics.f2j],
+            wl.job_total_bytes[statics.f2j] * statics.spj_inv)
+        comm_elapsed = jnp.clip((t - comm_start) / wl.period[statics.f2j],
+                                0.0, 1.0)
+        est_finish = jnp.clip(to_deliver / jnp.maximum(rate, 1.0)
+                              / wl.period[statics.f2j], 0.0, 1.0)
 
-    # the kernel path takes the same traced DynamicParams as the oracle:
-    # protocol scalars are operands of the fused kernel (DESIGN.md §4), so
-    # K=1 and K>1 sweeps share this one dispatch
-    tick_fn = core.cc_tick
-    dyn = sweep.dyn()
-    if cfg.use_pallas_kernel:
-        from repro.kernels import ops as kernel_ops
-        tick_fn = kernel_ops.mltcp_cc_tick
-    static_factors = (sweep.static_job_factors[statics.f2j]
-                      if sweep.static_job_factors is not None else None)
-    proto, _ = tick_fn(
-        cfg.protocol, st.proto, fb, flow_total,
-        flow_to_job=statics.f2j, n_jobs=J,
-        static_factors=static_factors,
-        comm_elapsed=comm_elapsed, est_finish=est_finish,
-        dyn=dyn)
+        # the kernel path takes the same traced DynamicParams as the oracle:
+        # protocol scalars are operands of the fused kernel (DESIGN.md §4), so
+        # K=1 and K>1 sweeps share this one dispatch
+        tick_fn = core.cc_tick
+        dyn = sweep.dyn()
+        if cfg.use_pallas_kernel:
+            from repro.kernels import ops as kernel_ops
+            tick_fn = kernel_ops.mltcp_cc_tick
+        static_factors = (sweep.static_job_factors[statics.f2j]
+                          if sweep.static_job_factors is not None else None)
+        proto, _ = tick_fn(
+            cfg.protocol, st.proto, fb, flow_total,
+            flow_to_job=statics.f2j, n_jobs=J,
+            static_factors=static_factors,
+            comm_elapsed=comm_elapsed, est_finish=est_finish,
+            dyn=dyn)
 
-    # CUBIC epoch reset on comm start (idle handling; see DESIGN.md)
-    if (cfg.cubic_epoch_reset_on_comm_start
-            and cfg.protocol.cc.algo == int(core.Algo.CUBIC)):
-        cc = proto.cc._replace(
-            epoch_start=jnp.where(enter_f, t, proto.cc.epoch_start),
-            w_max=jnp.where(enter_f, proto.cc.cwnd, proto.cc.w_max))
-        proto = proto._replace(cc=cc)
+        # CUBIC epoch reset on comm start (idle handling; see DESIGN.md)
+        if (cfg.cubic_epoch_reset_on_comm_start
+                and cfg.protocol.cc.algo == int(core.Algo.CUBIC)):
+            cc = proto.cc._replace(
+                epoch_start=jnp.where(enter_f, t, proto.cc.epoch_start),
+                w_max=jnp.where(enter_f, proto.cc.cwnd, proto.cc.w_max))
+            proto = proto._replace(cc=cc)
 
     # ------------------------------------------------------------------
     # 7. Trace accumulators
     # ------------------------------------------------------------------
-    acc_util = st.acc_util + dep[:M].sum(axis=1) / (statics.cap * dt)
-    acc_drops = st.acc_drops + dropped_f.sum() / mss
-    acc_marks = st.acc_marks + marked_f.sum() / mss
-    acc_jobbytes = st.acc_jobbytes.at[statics.f2j].add(delivered)
+    with jax.named_scope("tick.accumulate"):
+        acc_util = st.acc_util + dep[:M].sum(axis=1) / (statics.cap * dt)
+        acc_drops = st.acc_drops + dropped_f.sum() / mss
+        acc_marks = st.acc_marks + marked_f.sum() / mss
+        acc_jobbytes = st.acc_jobbytes.at[statics.f2j].add(delivered)
 
     # ------------------------------------------------------------------
     # 8. Telemetry probes + streaming detectors (off = this block vanishes)
     # ------------------------------------------------------------------
-    tstate = st.telemetry
-    if cfg.telemetry is not None:
-        spec = cfg.telemetry
-        f_job = None
-        if spec.wants("job_f"):
-            # recompute the factor stage from the post-update detection
-            # state (the kernel path doesn't return per-flow F), then
-            # average socket factors per job
-            f_flow = core.f_values(cfg.protocol, proto.det, fb,
-                                   comm_elapsed, est_finish, dyn,
-                                   static_factors=static_factors)
-            f_job = (jnp.zeros((J,), jnp.float32).at[statics.f2j]
-                     .add(f_flow * statics.spj_inv))
-        # a churn-departed job leaves the interleave statistic exactly like
-        # a padded-out job: fold the current churn row into the activity
-        # mask (identity row is all-True -> an exact no-op `&`)
-        telem_active = sweep.job_active
-        if churn_row is not None:
-            telem_active = (churn_row if telem_active is None
-                            else telem_active & churn_row)
-        sig = telem.TickSignals(
-            tick=st.tick, t=t,
-            cwnd=proto.cc.cwnd, rate=rate,
-            bytes_ratio=proto.det.bytes_ratio,
-            q_len=q_len, red_prob=p_red,
-            in_comm=in_comm, phase_idx=phase_idx, iter_idx=iter_idx,
-            iter_done=iter_done, iter_time=iter_time,
-            f_job=f_job, job_active=telem_active,
-            fault_idx=fault_idx,
-            fault_ticks=(sweep.fault_tick if cfg.faults is not None
-                         else None))
-        tstate = telem.tick_update(cfg, spec, st.telemetry, sig)
+    with jax.named_scope("tick.telemetry"):
+        tstate = st.telemetry
+        if cfg.telemetry is not None:
+            spec = cfg.telemetry
+            f_job = None
+            if spec.wants("job_f"):
+                # recompute the factor stage from the post-update detection
+                # state (the kernel path doesn't return per-flow F), then
+                # average socket factors per job
+                f_flow = core.f_values(cfg.protocol, proto.det, fb,
+                                       comm_elapsed, est_finish, dyn,
+                                       static_factors=static_factors)
+                f_job = (jnp.zeros((J,), jnp.float32).at[statics.f2j]
+                         .add(f_flow * statics.spj_inv))
+            # a churn-departed job leaves the interleave statistic exactly like
+            # a padded-out job: fold the current churn row into the activity
+            # mask (identity row is all-True -> an exact no-op `&`)
+            telem_active = sweep.job_active
+            if churn_row is not None:
+                telem_active = (churn_row if telem_active is None
+                                else telem_active & churn_row)
+            sig = telem.TickSignals(
+                tick=st.tick, t=t,
+                cwnd=proto.cc.cwnd, rate=rate,
+                bytes_ratio=proto.det.bytes_ratio,
+                q_len=q_len, red_prob=p_red,
+                in_comm=in_comm, phase_idx=phase_idx, iter_idx=iter_idx,
+                iter_done=iter_done, iter_time=iter_time,
+                f_job=f_job, job_active=telem_active,
+                fault_idx=fault_idx,
+                fault_ticks=(sweep.fault_tick if cfg.faults is not None
+                             else None))
+            tstate = telem.tick_update(cfg, spec, st.telemetry, sig)
 
     return EngineState(
         proto=proto, backlog=backlog, transit=transit,
@@ -979,14 +994,17 @@ def _run_single(cfg: SimConfig, statics: TickStatics,
     tick = partial(_tick, cfg, statics, sweep, _workload_view(cfg, sweep))
 
     def chunk(st: EngineState, _):
-        st = st._replace(acc_util=jnp.zeros_like(st.acc_util),
-                         acc_drops=jnp.asarray(0.0, jnp.float32),
-                         acc_marks=jnp.asarray(0.0, jnp.float32),
-                         acc_jobbytes=jnp.zeros_like(st.acc_jobbytes))
+        with jax.named_scope("chunk.reset"):
+            st = st._replace(acc_util=jnp.zeros_like(st.acc_util),
+                             acc_drops=jnp.asarray(0.0, jnp.float32),
+                             acc_marks=jnp.asarray(0.0, jnp.float32),
+                             acc_jobbytes=jnp.zeros_like(st.acc_jobbytes))
         st, _ = jax.lax.scan(tick, st, None, length=ticks_per_chunk)
         # the legacy chunk-averaged channels, via the built-in chunk-probe
         # registry (telemetry.CHUNK_PROBES — same expressions, same order)
-        return st, telem.chunk_capture(cfg, statics, st, ticks_per_chunk)
+        with jax.named_scope("chunk.capture"):
+            out = telem.chunk_capture(cfg, statics, st, ticks_per_chunk)
+        return st, out
 
     st, (u, d, m, ic, tt, jt, rj) = jax.lax.scan(chunk, st, None,
                                                  length=n_chunks)
@@ -1101,13 +1119,11 @@ def simulate_sweep(cfg: SimConfig, sweep: SweepParams) -> RawSimOutput:
 def lower_sweep(cfg: SimConfig, sweep: SweepParams):
     """AOT-lower the sweep program (`jax.stages.Lowered`) without running it.
 
-    The profiling hook behind `run_plan(..., profile=True)`: callers split
-    wall time into trace (`lower_sweep`), compile (`.compile()`) and execute
-    (calling the compiled object), and read `.memory_analysis()` for the
-    device footprint.  Shares `_run_sweep`'s jit/lowering cache (pin with
-    `TRACE_COUNT` if retrace behavior matters), but `.compile()` on the
-    returned object re-runs XLA, so the compile_s split is only meaningful
-    for cold groups.
+    The static analyzer's HLO budget layer compiles the returned object and
+    reads its cost and memory analyses (`roofline.hlo.cost_envelope`).
+    Shares `_run_sweep`'s jit/lowering cache (pin with `TRACE_COUNT` if
+    retrace behavior matters), but `.compile()` on the returned object
+    runs XLA again (or loads from the persistent compilation cache).
     """
     _validate_sweep(cfg, sweep)
     return _run_sweep.lower(cfg, sweep, _sweep_mesh(sweep))

@@ -56,7 +56,6 @@ import hashlib
 import math
 import os
 import pickle
-import time
 import warnings
 from typing import Callable, Optional
 
@@ -65,7 +64,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.netsim import counters
-from repro.netsim import engine as engine_mod
 from repro.netsim import metrics
 from repro.netsim.engine import (
     SimConfig,
@@ -526,38 +524,40 @@ def _shard_sweep(sweep: SweepParams, k: int,
 class GroupProfile:
     """Runtime profile of one compile group's sweep execution.
 
-    Always records the end-to-end wall time and whether the call traced a
-    new program (``traced``; False = served from the jit cache).  The
-    trace/compile/execute split and the device-memory footprint are only
-    available under ``run_plan(..., profile=True)``, which AOT-lowers the
-    group (`engine.lower_sweep`) and pays a fresh XLA compile per call, so
-    it is opt-in and the split fields are None otherwise.
+    Host seconds of the group's three `run_plan` phases, each a
+    `counters.span` of the same name: stacking the points' parameters
+    (``stack_s``, ``run_plan.stack``), the device call with its
+    ``block_until_ready`` (``wall_s``, ``run_plan.device``; trace and
+    compile included when the group traced a new program, ``traced``) and
+    the per-point postprocess and cache save (``postprocess_s``,
+    ``run_plan.postprocess``).
     """
 
     n_points: int                     # K lowered onto the sweep axis
     n_jobs: int                       # group fabric size (padded)
     n_flows: int
     n_ticks: int                      # per simulation
-    wall_s: float                     # end-to-end (trace+compile+execute)
+    wall_s: float                     # device call (trace+compile+execute)
     traced: bool
-    trace_s: Optional[float] = None
-    compile_s: Optional[float] = None
-    execute_s: Optional[float] = None
-    device_bytes: Optional[int] = None  # temp+output footprint, if exposed
-    cost_envelope: Optional[dict] = None  # roofline.hlo.cost_envelope keys
-    signature: Optional[str] = None     # _group_signature, for budget keys
+    signature: Optional[str] = None     # _group_signature
+    stack_s: float = 0.0
+    postprocess_s: float = 0.0
 
 
 @dataclasses.dataclass
 class PlanProfile:
     """Per-group runtime profiles of one `run_plan` call.
 
-    The costing input for scheduling follow-ons (ROADMAP: sharding *across*
-    compile groups needs per-group cost estimates — this is where they come
-    from).
+    ``prepare_s`` is the call's ``run_plan.prepare`` span (points, builds,
+    overrides, cache lookups, grouping); ``jit_s`` the call's
+    `counters.jit_seconds` deltas (trace, lower, compile, cache_load).
+    The costing input for scheduling follow-ons (ROADMAP: sharding
+    *across* compile groups needs per-group cost estimates).
     """
 
     groups: list[GroupProfile] = dataclasses.field(default_factory=list)
+    prepare_s: float = 0.0
+    jit_s: dict = dataclasses.field(default_factory=dict)
 
     @property
     def total_wall_s(self) -> float:
@@ -571,18 +571,13 @@ class PlanProfile:
     def summary(self) -> dict:
         out = {"n_groups": len(self.groups),
                "wall_s": round(self.total_wall_s, 3),
-               "n_traced": sum(g.traced for g in self.groups)}
-        if any(g.compile_s is not None for g in self.groups):
-            out["trace_s"] = round(sum(g.trace_s or 0.0
-                                       for g in self.groups), 3)
-            out["compile_s"] = round(sum(g.compile_s or 0.0
-                                         for g in self.groups), 3)
-            out["execute_s"] = round(sum(g.execute_s or 0.0
-                                         for g in self.groups), 3)
-        mem = [g.device_bytes for g in self.groups
-               if g.device_bytes is not None]
-        if mem:
-            out["peak_group_device_bytes"] = max(mem)
+               "n_traced": sum(g.traced for g in self.groups),
+               "prepare_s": round(self.prepare_s, 3),
+               "stack_s": round(sum(g.stack_s for g in self.groups), 3),
+               "postprocess_s": round(sum(g.postprocess_s
+                                          for g in self.groups), 3)}
+        for kind, secs in self.jit_s.items():
+            out[f"{kind}_s"] = round(secs, 3)
         return out
 
 
@@ -636,8 +631,7 @@ class PlanResult:
     # points served from run_plan's cache_dir (0 without a cache);
     # n_compile_groups counts only the groups actually simulated.
     n_cache_hits: int = 0
-    # per-group runtime profile (wall times always; the trace/compile/
-    # execute split and device footprint under run_plan(..., profile=True))
+    # host seconds of the call's phases, per group, and its jit seconds
     profile: PlanProfile = dataclasses.field(default_factory=PlanProfile)
     # compile groups that failed under keep_going=True (empty otherwise —
     # the default keep_going=False re-raises at the failing group)
@@ -944,43 +938,9 @@ def group_sweep(cfgs: list[SimConfig], overrides: list[dict],
     return _stack_params(per_point)
 
 
-def _run_group_profiled(cfg: SimConfig, sweep: SweepParams,
-                        prof: GroupProfile):
-    """AOT-lowered group execution with a trace/compile/execute wall-time
-    split and the compiled program's device-memory footprint."""
-    with counters.watch() as w:
-        t0 = time.perf_counter()
-        lowered = engine_mod.lower_sweep(cfg, sweep)
-        t1 = time.perf_counter()
-        compiled = lowered.compile()
-        t2 = time.perf_counter()
-        raw = compiled(sweep)
-        jax.block_until_ready(raw)
-        t3 = time.perf_counter()
-    prof.trace_s = t1 - t0
-    prof.compile_s = t2 - t1
-    prof.execute_s = t3 - t2
-    prof.wall_s = t3 - t0
-    prof.traced = w.traces > 0
-    try:
-        mem = compiled.memory_analysis()
-        prof.device_bytes = int(mem.temp_size_in_bytes
-                                + mem.output_size_in_bytes
-                                + mem.argument_size_in_bytes)
-    except Exception:               # backend doesn't expose the analysis
-        prof.device_bytes = None
-    try:
-        from repro.roofline import hlo as hlo_mod
-        prof.cost_envelope = hlo_mod.cost_envelope(compiled)
-    except Exception:               # backend doesn't expose cost analysis
-        prof.cost_envelope = None
-    return raw
-
-
 def run_plan(plan: Plan, *, shard="auto", pad_jobs: bool = True,
              cache_dir: Optional[str] = None,
              telemetry: Optional[TelemetrySpec] = None,
-             profile: bool = False,
              keep_going: bool = False) -> PlanResult:
     """Execute a plan: one `simulate_sweep` per compile group.
 
@@ -1001,11 +961,6 @@ def run_plan(plan: Plan, *, shard="auto", pad_jobs: bool = True,
                a `.telemetry` with the probe series and detector outputs.
                None leaves the built configs untouched — a build function
                may still arm points itself.
-    profile:   record a trace/compile/execute wall-time split and device
-               footprint per compile group into `PlanResult.profile` via
-               AOT lowering.  The AOT `.compile()` re-runs XLA on every
-               call, so it is opt-in; the default path still profiles
-               end-to-end wall time and whether each group (re)traced.
     keep_going: isolate per-group failures — a compile group that raises
                (bad config, OOM, compile error) is recorded on
                `PlanResult.group_errors` (its members' result slots stay
@@ -1014,60 +969,65 @@ def run_plan(plan: Plan, *, shard="auto", pad_jobs: bool = True,
                default (False) re-raises at the failing group, exactly the
                pre-existing behavior.
     """
-    points = plan.points()
-    cfgs = [plan.build(dict(pt)) for pt in points]
-    if telemetry is not None:
-        cfgs = [dataclasses.replace(c, telemetry=telemetry) for c in cfgs]
-    overrides = _resolve_overrides(plan, points, cfgs)
-
-    results: list[Optional[metrics.SimResult]] = [None] * len(points)
-    keys: list[Optional[str]] = [None] * len(points)
-    if cache_dir is not None:
-        os.makedirs(cache_dir, exist_ok=True)
-        for i in range(len(points)):
-            keys[i] = _point_cache_key(cfgs[i], overrides[i])
-            results[i] = _cache_load(cache_dir, keys[i])
-    n_cache_hits = sum(r is not None for r in results)
-    todo = [i for i in range(len(points)) if results[i] is None]
-
-    groups = _compile_groups([cfgs[i] for i in todo], pad_jobs)
-    plan_profile = PlanProfile()
     group_errors: list[GroupError] = []
     with counters.watch(reset_warnings=True) as plan_watch:
+        with counters.span("run_plan.prepare") as prepare:
+            points = plan.points()
+            cfgs = [plan.build(dict(pt)) for pt in points]
+            if telemetry is not None:
+                cfgs = [dataclasses.replace(c, telemetry=telemetry)
+                        for c in cfgs]
+            overrides = _resolve_overrides(plan, points, cfgs)
+
+            results: list[Optional[metrics.SimResult]] = [None] * len(points)
+            keys: list[Optional[str]] = [None] * len(points)
+            if cache_dir is not None:
+                os.makedirs(cache_dir, exist_ok=True)
+                for i in range(len(points)):
+                    keys[i] = _point_cache_key(cfgs[i], overrides[i])
+                    results[i] = _cache_load(cache_dir, keys[i])
+            n_cache_hits = sum(r is not None for r in results)
+            todo = [i for i in range(len(points)) if results[i] is None]
+
+            groups = _compile_groups([cfgs[i] for i in todo], pad_jobs)
+        plan_profile = PlanProfile(prepare_s=prepare.seconds)
         for gi, group in enumerate(groups):
             idxs = [todo[j] for j in group.idxs]  # group indexes todo subset
+            k = len(idxs)
             try:
-                per_point = [_point_params(cfgs[i], overrides[i], group)
-                             for i in idxs]
-                sweep = _stack_params(per_point)
-                k = len(idxs)
-                sweep, _ = _shard_sweep(sweep, k, shard)
+                with counters.span("run_plan.stack", group=gi,
+                                   points=k) as stack:
+                    per_point = [_point_params(cfgs[i], overrides[i], group)
+                                 for i in idxs]
+                    sweep = _stack_params(per_point)
+                    sweep, _ = _shard_sweep(sweep, k, shard)
                 prof = GroupProfile(n_points=k, n_jobs=group.cfg.jobs.n_jobs,
                                     n_flows=group.cfg.topo.n_flows,
                                     n_ticks=group.cfg.n_ticks,
                                     wall_s=0.0, traced=False,
-                                    signature=_group_signature(group))
-                if profile:
-                    raw = _run_group_profiled(group.cfg, sweep, prof)
-                else:
-                    with counters.watch() as w:
-                        t0 = time.perf_counter()
-                        raw = simulate_sweep(group.cfg, sweep)
-                        jax.block_until_ready(raw)
-                        prof.wall_s = time.perf_counter() - t0
-                    prof.traced = w.traces > 0
+                                    signature=_group_signature(group),
+                                    stack_s=stack.seconds)
+                with counters.watch() as w, counters.span(
+                        "run_plan.device", group=gi, points=k) as device:
+                    raw = simulate_sweep(group.cfg, sweep)
+                    jax.block_until_ready(raw)
+                prof.wall_s = device.seconds
+                prof.traced = w.traces > 0
                 plan_profile.groups.append(prof)
-                for slot, i in enumerate(idxs):
-                    point = SweepPoint(axes=dict(points[i]),
-                                       params=per_point[slot],
-                                       n_jobs=cfgs[i].jobs.n_jobs)
-                    raw_i = jax.tree_util.tree_map(lambda x, s=slot: x[s],
-                                                   raw)
-                    results[i] = metrics.postprocess(cfgs[i], raw_i,
-                                                     point=point,
-                                                     n_jobs=point.n_jobs)
-                    if cache_dir is not None:
-                        _cache_save(cache_dir, keys[i], results[i])
+                with counters.span("run_plan.postprocess", group=gi,
+                                   points=k) as post:
+                    for slot, i in enumerate(idxs):
+                        point = SweepPoint(axes=dict(points[i]),
+                                           params=per_point[slot],
+                                           n_jobs=cfgs[i].jobs.n_jobs)
+                        raw_i = jax.tree_util.tree_map(
+                            lambda x, s=slot: x[s], raw)
+                        results[i] = metrics.postprocess(cfgs[i], raw_i,
+                                                         point=point,
+                                                         n_jobs=point.n_jobs)
+                        if cache_dir is not None:
+                            _cache_save(cache_dir, keys[i], results[i])
+                prof.postprocess_s = post.seconds
             except Exception as exc:
                 if not keep_going:
                     raise
@@ -1077,6 +1037,7 @@ def run_plan(plan: Plan, *, shard="auto", pad_jobs: bool = True,
                     point_labels=[SweepPoint(axes=dict(points[i])).label()
                                   for i in idxs],
                     error=f"{type(exc).__name__}: {exc}"))
+    plan_profile.jit_s = plan_watch.jit_s
     return PlanResult(plan=plan, results=results,
                       n_compile_groups=len(groups),
                       n_kernel_fallbacks=plan_watch.fallbacks,

@@ -1,6 +1,6 @@
 """On-device probe subsystem (`netsim.telemetry`) — the off-is-free
 invariant, decimation correctness, detector == NumPy replay, trace-count
-pinning, and the plan-layer plumbing (telemetry=, profile=, cache
+pinning, and the plan-layer plumbing (telemetry=, phase profile, cache
 versioning, per-plan fallback-warning reset)."""
 import dataclasses
 import math
@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro import netsim
-from repro.netsim import engine, telemetry
+from repro.netsim import counters, engine, telemetry
 from repro.core import Algo, CCParams, MLTCPConfig, Variant
 
 DT = 2e-5
@@ -266,17 +266,17 @@ def _mini_plan(**build_kw):
 
 
 def test_profile_split_fields():
-    pr = netsim.run_plan(_mini_plan(), profile=True)
-    (g,) = pr.profile.groups
-    assert g.trace_s is not None and g.compile_s is not None
-    assert g.execute_s is not None and g.wall_s > 0
+    pr = netsim.run_plan(_mini_plan())
+    prof = pr.profile
+    (g,) = prof.groups
+    assert g.stack_s > 0 and g.wall_s > 0 and g.postprocess_s > 0
     assert g.n_points == 2 and g.n_ticks == 2500
-    s = pr.profile.summary()
-    assert s["n_groups"] == 1 and "compile_s" in s
-    # default path: split fields stay None
-    pr2 = netsim.run_plan(_mini_plan())
-    assert pr2.profile.groups[0].compile_s is None
-    assert pr2.profile.total_ticks == 2 * 2500
+    assert prof.prepare_s > 0
+    assert set(prof.jit_s) == set(counters.JIT_KINDS)
+    s = prof.summary()
+    assert s["n_groups"] == 1 and "compile_s" in s and "trace_s" in s
+    assert s["stack_s"] > 0 and s["postprocess_s"] > 0
+    assert prof.total_ticks == 2 * 2500
 
 
 def test_cache_versioned_and_pruned(tmp_path):
