@@ -15,11 +15,16 @@ surface:
         run_plan(plan)
     assert w.traces == 2 and w.fallbacks == 0
 
-``watch()`` snapshots both counters at entry; the returned handle's
-``.traces`` / ``.fallbacks`` are live deltas (they keep counting after the
-``with`` block exits, so reading them post-exit sees everything the block
-did).  Reading never imports ``repro.kernels`` — a plan that never enables
-``use_pallas_kernel`` shouldn't pay the kernel import.
+A third, ``routes()``, counts the sweep-program traces by how their link
+stage routes departures (``engine.ROUTE_COUNT``): ``single_hop`` where
+every path is one link, ``select`` where some flow is forwarded.
+
+``watch()`` snapshots the counters at entry; the returned handle's
+``.traces`` / ``.routes`` / ``.fallbacks`` are live deltas (they keep
+counting after the ``with`` block exits, so reading them post-exit sees
+everything the block did).  Reading never imports ``repro.kernels`` — a
+plan that never enables ``use_pallas_kernel`` shouldn't pay the kernel
+import.
 
 Two more pieces measure where time goes, on the profiler's clock:
 
@@ -52,8 +57,8 @@ import time
 
 import jax
 
-__all__ = ["traces", "fallbacks", "reset_fallback_warnings", "jit_seconds",
-           "span", "recent_spans", "watch", "CounterWatch"]
+__all__ = ["traces", "routes", "fallbacks", "reset_fallback_warnings",
+           "jit_seconds", "span", "recent_spans", "watch", "CounterWatch"]
 
 # jax.monitoring events -> jit_seconds() kinds.  Trace, lower and compile
 # come as time spans; a jit traced inside another's trace records a span
@@ -117,6 +122,14 @@ def traces() -> int:
     from repro.netsim import engine
 
     return engine.TRACE_COUNT
+
+
+def routes() -> dict[str, int]:
+    """Sweep-program traces this process, by the link stage's routing form
+    (``engine.ROUTE_COUNT``)."""
+    from repro.netsim import engine
+
+    return dict(engine.ROUTE_COUNT)
 
 
 def fallbacks() -> int:
@@ -194,12 +207,18 @@ class CounterWatch:
 
     def __init__(self) -> None:
         self._traces0 = traces()
+        self._routes0 = routes()
         self._fallbacks0 = fallbacks()
         self._jit0 = jit_seconds()
 
     @property
     def traces(self) -> int:
         return traces() - self._traces0
+
+    @property
+    def routes(self) -> dict[str, int]:
+        """`routes()` deltas, by routing form."""
+        return {k: n - self._routes0[k] for k, n in routes().items()}
 
     @property
     def fallbacks(self) -> int:

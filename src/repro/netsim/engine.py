@@ -506,36 +506,75 @@ class TickStatics(NamedTuple):
     """
 
     cap: Array            # [M]
-    first_link: Array     # [N]
-    next_link: Array      # [M+1, N] (M = trash/delivered)
+    first_hot: Array      # [M+1, N] bool: l is n's first link
+    is_final: Array       # [M+1, N] bool: l is n's last link (delivers)
+    prev_link: Array      # [M+1, N] link before l on n's path, -1 if none
+    forwarders: tuple[int, ...]  # links that forward to a next hop
     f2j: Array            # [N]
     spj_inv: Array        # [N] 1/flows-in-job
     n_phases: Array       # [J]
     start_offset: Array   # [J]
 
+    @property
+    def route(self) -> str:
+        """The link stage's routing form: "single_hop" where no link
+        forwards (the route is zero), "select" otherwise."""
+        return "select" if self.forwarders else "single_hop"
+
 
 def _build_statics(cfg: SimConfig) -> TickStatics:
     topo, jobs = cfg.topo, cfg.jobs
     M, N = topo.n_links, topo.n_flows
-    hops = topo.hops
-    first_link = hops[:, 0].astype(np.int32)
-    # next_link[l, n]: link after l on n's path; M (trash) means "delivered".
-    nxt = np.full((M + 1, N), M, np.int32)
+    # Departures route by static selects: link l of flow n takes its bytes
+    # from the one link before it on n's path.  (On a TPU a scatter or a
+    # gather over the (link, flow) rows costs a step more than the selects.)
+    # Row M (the trash row) is no link's successor and no flow's first
+    # link, so it stays zero in backlog and transit.
+    first_hot = np.zeros((M + 1, N), bool)
+    is_final = np.zeros((M + 1, N), bool)
+    prev = np.full((M + 1, N), -1, np.int32)
     for n in range(N):
-        path = [l for l in hops[n] if l >= 0]
-        for i, l in enumerate(path):
-            nxt[l, n] = path[i + 1] if i + 1 < len(path) else M
+        path = [int(l) for l in topo.hops[n] if l >= 0]
+        if len(set(path)) != len(path):
+            raise ValueError(f"flow {n}'s path {path} repeats a link; the "
+                             f"link stage keeps one predecessor per link")
+        if path:
+            first_hot[path[0], n] = True
+            is_final[path[-1], n] = True
+        for a, b in zip(path, path[1:]):
+            prev[b, n] = a
     f2j = topo.flow_to_job.astype(np.int32)
     spj = np.bincount(f2j, minlength=jobs.n_jobs).astype(np.float64)
     return TickStatics(
         cap=jnp.asarray(topo.cap, jnp.float32),
-        first_link=jnp.asarray(first_link),
-        next_link=jnp.asarray(nxt),
+        first_hot=jnp.asarray(first_hot),
+        is_final=jnp.asarray(is_final),
+        prev_link=jnp.asarray(prev),
+        forwarders=tuple(int(l) for l in np.unique(prev[prev >= 0])),
         f2j=jnp.asarray(f2j),
         spj_inv=jnp.asarray(1.0 / spj[f2j], jnp.float32),
         n_phases=jnp.asarray(jobs.n_phases, jnp.int32),
         start_offset=jnp.asarray(jobs.start_offset, jnp.float32),
     )
+
+
+def _enqueue(statics: TickStatics, transit: Array, inj: Array) -> Array:
+    """Bytes entering each link this tick [M+1, N]: what the previous link
+    forwarded (``transit``), plus each flow's injection at its first link."""
+    return jnp.where(statics.first_hot, transit + inj, transit)
+
+
+def _route(statics: TickStatics, dep: Array) -> tuple[Array, Array]:
+    """Departures ``dep`` [M+1, N] -> (bytes delivered per flow [N], bytes
+    arriving at each link next tick [M+1, N]).  A flow's last link
+    delivers; every other link forwards to the next one on the path."""
+    delivered = jnp.sum(dep * statics.is_final, axis=0)
+    # one select per forwarding link; each element takes one link's bytes
+    # or none, so no sum is formed (a one-hop fabric forwards nothing)
+    transit = jnp.zeros_like(dep)
+    for l in statics.forwarders:
+        transit = jnp.where(statics.prev_link == l, dep[l], transit)
+    return delivered, transit
 
 
 class _WorkloadView(NamedTuple):
@@ -644,7 +683,6 @@ def _tick(cfg: SimConfig, statics: TickStatics, sweep: SweepParams,
     N = cfg.topo.n_flows
     J = cfg.jobs.n_jobs
     mss = cfg.protocol.cc.mss
-    arange_n = jnp.arange(N)
 
     # each stage runs under a named scope: HLO op_name metadata only, by
     # which a profiler trace's device ops are told apart per stage
@@ -740,9 +778,7 @@ def _tick(cfg: SimConfig, statics: TickStatics, sweep: SweepParams,
     # 3. Links: enqueue (RED) -> serve -> route departures
     # ------------------------------------------------------------------
     with jax.named_scope("tick.links"):
-        incoming = st.transit
-        incoming = incoming.at[statics.first_link, arange_n].add(inj)
-        incoming = incoming.at[M].set(0.0)                       # trash row
+        incoming = _enqueue(statics, st.transit, inj)
 
         q_len = st.backlog[:M].sum(axis=1)                           # [M]
         p_red = _red_prob(sweep, q_len)                              # [M]
@@ -776,16 +812,8 @@ def _tick(cfg: SimConfig, statics: TickStatics, sweep: SweepParams,
         serve_full = jnp.concatenate([serve_ratio, jnp.zeros((1,))])
         dep = backlog * serve_full[:, None]
         backlog = backlog - dep
-        backlog = backlog.at[M].set(0.0)
 
-        # route departures: next_link == M means delivered
-        is_final = statics.next_link == M                            # [M+1, N]
-        delivered = jnp.sum(dep * is_final, axis=0)                  # [N]
-        fwd = dep * (~is_final)
-        transit = jnp.zeros_like(st.transit).at[
-            statics.next_link.reshape(-1), jnp.tile(arange_n, M + 1)
-        ].add(fwd.reshape(-1))
-        transit = transit.at[M].set(0.0)
+        delivered, transit = _route(statics, dep)
 
         # per-flow drop / mark signals.  The barrier pins the flow vector as a
         # materialized value: otherwise XLA may merge `dropped_f.sum()` below
@@ -1018,6 +1046,8 @@ def _run_single(cfg: SimConfig, statics: TickStatics,
 # Incremented once per (re)trace of the sweep program; tests pin "a K-point
 # sweep costs exactly one trace" on this counter.
 TRACE_COUNT = 0
+# Sweep-program traces by the link stage's routing form (`TickStatics.route`).
+ROUTE_COUNT = {"single_hop": 0, "select": 0}
 
 
 @partial(jax.jit, static_argnums=(0, 2))
@@ -1026,6 +1056,7 @@ def _run_sweep(cfg: SimConfig, sweep: SweepParams,
     global TRACE_COUNT
     TRACE_COUNT += 1
     statics = _build_statics(cfg)
+    ROUTE_COUNT[statics.route] += 1
     run = jax.vmap(lambda s: _run_single(cfg, statics, s))
     if mesh is not None:
         # Mosaic kernels cannot be partitioned automatically: each device

@@ -1,9 +1,13 @@
 """Netsim engine invariants + the paper's headline system behaviours."""
+import re
+
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import netsim, workload
 from repro.core import Algo, CCParams, MLTCPConfig, Variant
+from repro.netsim import counters, engine
 
 DT = 2e-5
 
@@ -149,3 +153,100 @@ def test_engine_with_pallas_kernel_matches_jnp():
     b = netsim.postprocess(cfg, netsim.simulate(cfg))
     assert abs(a.avg_iter(0) - b.avg_iter(0)) / a.avg_iter(0) < 1e-3
     assert len(a.iter_times[0]) == len(b.iter_times[0])
+
+
+# ---------------------------------------------------------------------------
+# The link stage's routing: dense selects over static per-flow paths
+# ---------------------------------------------------------------------------
+
+ROUTING_FABRICS = {
+    "dumbbell": lambda: netsim.dumbbell(7, sockets_per_job=8),
+    "triangle": lambda: netsim.triangle(2),
+    "two_tier": lambda: netsim.two_tier([(0, 1), (1, 2), (2, 3), (3, 0)],
+                                        n_leaves=4),
+}
+
+
+def _fabric_cfg(topo, sim_time=0.3, **kw):
+    jobs = netsim.JobSpec.simple([0.005] * topo.n_jobs,
+                                 [8e6] * topo.n_jobs)
+    return netsim.SimConfig(topo=topo, jobs=jobs, protocol=_proto(),
+                            sim_time=sim_time, dt=DT, seed=3, **kw)
+
+
+def _scatter_links(topo, transit, inj, dep):
+    """The link stage's enqueue and route as scatter-adds over a dense
+    next-link table (row M is the trash row, zeroed after each scatter)."""
+    M, N = topo.n_links, topo.n_flows
+    arange_n = jnp.arange(N)
+    nxt = np.full((M + 1, N), M, np.int32)
+    for n in range(N):
+        path = [l for l in topo.hops[n] if l >= 0]
+        for i, l in enumerate(path):
+            nxt[l, n] = path[i + 1] if i + 1 < len(path) else M
+    incoming = transit.at[jnp.asarray(topo.hops[:, 0]), arange_n].add(inj)
+    incoming = incoming.at[M].set(0.0)
+    is_final = jnp.asarray(nxt) == M
+    delivered = jnp.sum(dep * is_final, axis=0)
+    fwd = dep * (~is_final)
+    routed = jnp.zeros_like(transit).at[
+        jnp.asarray(nxt).reshape(-1), jnp.tile(arange_n, M + 1)
+    ].add(fwd.reshape(-1))
+    return incoming, delivered, routed.at[M].set(0.0)
+
+
+@pytest.mark.parametrize("fabric", sorted(ROUTING_FABRICS))
+def test_link_routing_matches_scatter_adds(fabric):
+    """Enqueue and route give exactly what scatter-adds give, on bytes held
+    only where a flow's path runs (as the tick holds them), and leave the
+    trash row M zero."""
+    topo = ROUTING_FABRICS[fabric]()
+    statics = engine._build_statics(_fabric_cfg(topo))
+    M = topo.n_links
+    rng = np.random.default_rng(7)
+    on_path = np.vstack([topo.routing_matrix(), np.zeros((1, topo.n_flows))])
+    for _ in range(4):
+        transit, dep = (jnp.asarray(rng.exponential(3e4, on_path.shape)
+                                    * on_path, jnp.float32)
+                        for _ in range(2))
+        inj = jnp.asarray(rng.exponential(3e4, topo.n_flows), jnp.float32)
+        want_in, want_del, want_tr = _scatter_links(topo, transit, inj, dep)
+        incoming = engine._enqueue(statics, transit, inj)
+        delivered, routed = engine._route(statics, dep)
+        np.testing.assert_array_equal(incoming, want_in)
+        np.testing.assert_array_equal(delivered, want_del)
+        np.testing.assert_array_equal(routed, want_tr)
+        assert not np.any(incoming[M]) and not np.any(routed[M])
+    assert statics.route == ("single_hop" if topo.max_hops == 1
+                             else "select")
+
+
+def test_a_path_that_repeats_a_link_is_refused():
+    good = netsim.triangle(1)
+    looped = netsim.Topology(cap=good.cap,
+                             hops=np.array([[0, 2, 0], [1, 0, -1]], np.int32),
+                             flow_to_job=np.array([0, 1], np.int32),
+                             names=good.names)
+    with pytest.raises(ValueError, match="repeats a link"):
+        engine._build_statics(_fabric_cfg(looped))
+
+
+@pytest.mark.parametrize("fabric", ["dumbbell", "triangle"])
+def test_compiled_link_stage_has_no_scatter(fabric):
+    """The compiled sweep program routes link departures with no scatter:
+    no scatter op carries the ``tick.links`` scope."""
+    cfg = _fabric_cfg(ROUTING_FABRICS[fabric](), sim_time=0.002)
+    text = engine.lower_sweep(cfg, netsim.make_sweep(cfg, seed=[0, 1])) \
+        .compile().as_text()
+    links = [line for line in text.splitlines() if "tick.links" in line]
+    assert links
+    assert not [line for line in links if re.search(r"\sscatter\(", line)]
+
+
+def test_route_counter_reads_each_programs_form():
+    with counters.watch() as w:
+        for fabric in ("dumbbell", "triangle"):
+            cfg = _fabric_cfg(ROUTING_FABRICS[fabric](), sim_time=0.0021)
+            engine.trace_sweep(cfg, netsim.make_sweep(cfg))
+    assert w.traces == 2
+    assert w.routes == {"single_hop": 1, "select": 1}
