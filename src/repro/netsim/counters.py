@@ -17,14 +17,17 @@ surface:
 
 A third, ``routes()``, counts the sweep-program traces by how their link
 stage routes departures (``engine.ROUTE_COUNT``): ``single_hop`` where
-every path is one link, ``select`` where some flow is forwarded.
+every path is one link, ``select`` where some flow is forwarded.  A
+fourth, ``iter_records()``, counts them by the iteration record's length
+(``engine.RECORD_COUNT``): ``default`` with ``engine.MAX_ITERS`` slots,
+``bounded`` where `run_plan` sized it to its group.
 
 ``watch()`` snapshots the counters at entry; the returned handle's
-``.traces`` / ``.routes`` / ``.fallbacks`` are live deltas (they keep
-counting after the ``with`` block exits, so reading them post-exit sees
-everything the block did).  Reading never imports ``repro.kernels`` — a
-plan that never enables ``use_pallas_kernel`` shouldn't pay the kernel
-import.
+``.traces`` / ``.routes`` / ``.iter_records`` / ``.fallbacks`` are live
+deltas (they keep counting after the ``with`` block exits, so reading them
+post-exit sees everything the block did).  Reading never imports
+``repro.kernels`` — a plan that never enables ``use_pallas_kernel``
+shouldn't pay the kernel import.
 
 Two more pieces measure where time goes, on the profiler's clock:
 
@@ -57,7 +60,8 @@ import time
 
 import jax
 
-__all__ = ["traces", "routes", "fallbacks", "reset_fallback_warnings",
+__all__ = ["traces", "routes", "iter_records", "fallbacks",
+           "reset_fallback_warnings",
            "jit_seconds", "span", "recent_spans", "watch", "CounterWatch"]
 
 # jax.monitoring events -> jit_seconds() kinds.  Trace, lower and compile
@@ -130,6 +134,14 @@ def routes() -> dict[str, int]:
     from repro.netsim import engine
 
     return dict(engine.ROUTE_COUNT)
+
+
+def iter_records() -> dict[str, int]:
+    """Sweep-program traces this process, by the iteration record's length
+    (``engine.RECORD_COUNT``)."""
+    from repro.netsim import engine
+
+    return dict(engine.RECORD_COUNT)
 
 
 def fallbacks() -> int:
@@ -208,6 +220,7 @@ class CounterWatch:
     def __init__(self) -> None:
         self._traces0 = traces()
         self._routes0 = routes()
+        self._records0 = iter_records()
         self._fallbacks0 = fallbacks()
         self._jit0 = jit_seconds()
 
@@ -219,6 +232,11 @@ class CounterWatch:
     def routes(self) -> dict[str, int]:
         """`routes()` deltas, by routing form."""
         return {k: n - self._routes0[k] for k, n in routes().items()}
+
+    @property
+    def iter_records(self) -> dict[str, int]:
+        """`iter_records()` deltas, by record form."""
+        return {k: n - self._records0[k] for k, n in iter_records().items()}
 
     @property
     def fallbacks(self) -> int:

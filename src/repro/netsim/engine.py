@@ -49,6 +49,9 @@ from repro.netsim.topology import HashableConfig, Topology
 
 Array = jnp.ndarray
 
+# default length of the iteration record (`SimConfig.max_iters_recorded`)
+MAX_ITERS = 4096
+
 
 # ---------------------------------------------------------------------------
 # Configuration
@@ -125,7 +128,10 @@ class SimConfig(HashableConfig):
     static_job_factors: Optional[np.ndarray] = None
     cassini: Optional[CassiniSchedule] = None
     cubic_epoch_reset_on_comm_start: bool = True
-    max_iters_recorded: int = 4096
+    # slots of the iteration record (`EngineState.iter_times`); a job's
+    # iterations past the last slot all land in it.  `run_plan` lowers this
+    # to each group's proven bound (`iteration_bound`).
+    max_iters_recorded: int = MAX_ITERS
     n_chunks: int = 400               # trace resolution
     seed: int = 0
     use_pallas_kernel: bool = False   # route CC tick through kernels/ops.py
@@ -481,7 +487,8 @@ class EngineState(NamedTuple):
     iter_idx: Array       # [J]
     iter_start: Array     # [J]
     hold_until: Array     # [J]
-    iter_times: Array     # [J, MAX_ITERS]
+    iter_times: Array     # [J, L], L = cfg.max_iters_recorded: under
+    #                       run_plan the group's bound (`iteration_bound`)
     straggle_extra: Array # [J] sampled straggle time for current iteration
     key: Array
     tick: Array           # int32
@@ -592,6 +599,39 @@ def _workload_view(cfg: SimConfig, sweep: SweepParams) -> _WorkloadView:
     inv_cap = float(1.0 / np.asarray(cfg.topo.cap, np.float64).min())
     period = sweep.compute.sum(axis=-1) + total * jnp.float32(inv_cap)
     return _WorkloadView(job_total_bytes=total, period=period)
+
+
+def iteration_bound(cfg: SimConfig, compute: np.ndarray,
+                    job_active: Optional[np.ndarray] = None) -> int:
+    """The most iterations any active job can finish in ``cfg.n_ticks``
+    ticks, given the traced phase programs ``compute`` ([..., J, P]
+    seconds, the float32 values the sweep carries) and the ``job_active``
+    mask ([..., J], None: all active).
+
+    Each phase takes at least one tick, and at least the ticks its compute
+    countdown ``t_rem -= dt`` needs to reach zero.  In float32 one step
+    takes off at most ``dt + u * c`` from a countdown started at ``c``
+    (``u`` here is four times the round-off unit), so the countdown lasts at
+    least ``ceil(c / (dt + u * c))`` ticks.  Stragglers, start offsets,
+    Cassini holds, flaps and blackholes only lengthen an iteration; churn
+    may re-enter an interrupted comm sub-phase without its compute, so an
+    armed churn channel adds one iteration per fault event.
+    """
+    c = np.asarray(compute, np.float32).astype(np.float64)
+    dt = float(np.float32(cfg.dt))
+    with np.errstate(invalid="ignore", divide="ignore"):
+        ticks = np.ceil(c / (dt + 2.0 ** -22 * c))
+    ticks = np.where(ticks >= 1.0, ticks, 1.0)   # NaN and c <= 0 too
+    live = np.arange(c.shape[-1]) < np.asarray(cfg.jobs.n_phases)[:, None]
+    # a job finishes at most one iteration a tick, whatever its program
+    min_iter = np.maximum(np.where(live, ticks, 0.0).sum(axis=-1), 1.0)
+    n = cfg.n_ticks // min_iter + 1                               # [..., J]
+    if job_active is not None:
+        n = np.where(np.asarray(job_active, bool), n, 0)
+    bound = int(n.max()) if n.size else 0
+    if cfg.faults is not None and cfg.faults.churn:
+        bound += cfg.faults.n_events
+    return bound
 
 
 def _init_state(cfg: SimConfig, statics: TickStatics,
@@ -867,12 +907,12 @@ def _tick(cfg: SimConfig, statics: TickStatics, sweep: SweepParams,
 
         # iteration bookkeeping + straggler sampling for the next iteration
         iter_time = t - st.iter_start
-        iter_times = st.iter_times.at[
-            jnp.arange(J), jnp.minimum(st.iter_idx, cfg.max_iters_recorded - 1)
-        ].set(jnp.where(iter_done, iter_time,
-                        st.iter_times[jnp.arange(J),
-                                      jnp.minimum(st.iter_idx,
-                                                  cfg.max_iters_recorded - 1)]))
+        # one select over the whole record: slot iter_idx (the last slot
+        # once the record is full) of each finishing job takes its time
+        slots = st.iter_times.shape[-1]
+        slot = jnp.minimum(st.iter_idx, slots - 1)
+        write = (jnp.arange(slots) == slot[:, None]) & iter_done[:, None]
+        iter_times = jnp.where(write, iter_time[:, None], st.iter_times)
         iter_idx = st.iter_idx + iter_done.astype(jnp.int32)
         iter_start = jnp.where(iter_done, t, st.iter_start)
 
@@ -998,7 +1038,8 @@ def _tick(cfg: SimConfig, statics: TickStatics, sweep: SweepParams,
 # ---------------------------------------------------------------------------
 
 class RawSimOutput(NamedTuple):
-    iter_times: Array     # [J, MAX_ITERS] seconds (nan where unset)
+    iter_times: Array     # [J, L] seconds (nan where unset), L as in
+    #                       EngineState: the group's bound under run_plan
     iter_counts: Array    # [J]
     trace_util: Array     # [n_chunks, M] mean utilization per chunk
     trace_drops: Array    # [n_chunks] packets per chunk
@@ -1048,6 +1089,9 @@ def _run_single(cfg: SimConfig, statics: TickStatics,
 TRACE_COUNT = 0
 # Sweep-program traces by the link stage's routing form (`TickStatics.route`).
 ROUTE_COUNT = {"single_hop": 0, "select": 0}
+# Sweep-program traces by the iteration record's length: "default" with
+# MAX_ITERS slots, "bounded" with another (`run_plan` sizes each group's).
+RECORD_COUNT = {"bounded": 0, "default": 0}
 
 
 @partial(jax.jit, static_argnums=(0, 2))
@@ -1057,6 +1101,8 @@ def _run_sweep(cfg: SimConfig, sweep: SweepParams,
     TRACE_COUNT += 1
     statics = _build_statics(cfg)
     ROUTE_COUNT[statics.route] += 1
+    RECORD_COUNT["default" if cfg.max_iters_recorded == MAX_ITERS
+                 else "bounded"] += 1
     run = jax.vmap(lambda s: _run_single(cfg, statics, s))
     if mesh is not None:
         # Mosaic kernels cannot be partitioned automatically: each device

@@ -36,7 +36,8 @@ and `run_plan` does the partitioning (DESIGN.md §5):
      P_max (zero columns are inert under the `n_phases` mask);
   4. lower each group's points onto the `simulate_sweep` K axis — one
      trace, one compile, K simulations per group — optionally sharding K
-     across local devices;
+     across local devices; the group's iteration record is sized to the
+     most iterations its points can finish (`_bound_record`);
   5. post-process each point with its own (unpadded) config and attach a
      `SweepPoint`, so every `SimResult` names its axis coordinates.
 
@@ -70,6 +71,7 @@ from repro.netsim.engine import (
     JobSpec,
     SweepParams,
     SweepPoint,
+    iteration_bound,
     simulate_sweep,
     sweep_of,
 )
@@ -388,6 +390,24 @@ def _compile_groups(cfgs: list[SimConfig], pad_jobs: bool) -> list[_Group]:
 # Lowering a group onto the sweep axis
 # ---------------------------------------------------------------------------
 
+def _bound_record(cfg: SimConfig, sweep: SweepParams) -> SimConfig:
+    """The group config with its iteration record cut to the power of two
+    at or above the most iterations a point of ``sweep`` (the group's
+    stacked values) can finish (`engine.iteration_bound`), and never above
+    the config's own ``max_iters_recorded``.
+
+    The bound reads only the phase programs and the job mask, so a re-run
+    of the same programs in another order or with other seeds sizes the
+    record alike and reuses the compiled program.
+    """
+    active = (None if sweep.job_active is None
+              else np.asarray(sweep.job_active))
+    bound = iteration_bound(cfg, np.asarray(sweep.compute), active)
+    slots = 1 << max(bound - 1, 0).bit_length()
+    return dataclasses.replace(
+        cfg, max_iters_recorded=min(cfg.max_iters_recorded, slots))
+
+
 def _pad_rows(x: np.ndarray, j: int, fill) -> np.ndarray:
     if x.shape[0] >= j:
         return x
@@ -530,7 +550,8 @@ class GroupProfile:
     ``block_until_ready`` (``wall_s``, ``run_plan.device``; trace and
     compile included when the group traced a new program, ``traced``) and
     the per-point postprocess and cache save (``postprocess_s``,
-    ``run_plan.postprocess``).
+    ``run_plan.postprocess``).  ``iter_slots`` is the length of the
+    group's iteration record.
     """
 
     n_points: int                     # K lowered onto the sweep axis
@@ -542,6 +563,7 @@ class GroupProfile:
     signature: Optional[str] = None     # _group_signature
     stack_s: float = 0.0
     postprocess_s: float = 0.0
+    iter_slots: int = 0
 
 
 @dataclasses.dataclass
@@ -914,8 +936,9 @@ def resolve_plan(plan: Plan, *, pad_jobs: bool = True,
     Returns ``(points, cfgs, overrides, groups)``: the plan's label dicts,
     each point's built config (telemetry stamped on if given), its resolved
     dynamic overrides, and the predicted compile groups (each group's
-    ``idxs`` index into ``points``/``cfgs``).  This is exactly the grouping
-    a cache-less `run_plan` would execute — the static analyzer
+    ``idxs`` index into ``points``/``cfgs``; its ``cfg`` has the iteration
+    record `run_plan` gives it, `_bound_record`).  This is exactly the
+    grouping a cache-less `run_plan` would execute — the static analyzer
     (`repro.analysis`) lints these groups' lowerings before anything runs,
     and benchmark health checks compare the prediction against what a run
     actually compiled.
@@ -926,6 +949,8 @@ def resolve_plan(plan: Plan, *, pad_jobs: bool = True,
         cfgs = [dataclasses.replace(c, telemetry=telemetry) for c in cfgs]
     overrides = _resolve_overrides(plan, points, cfgs)
     groups = _compile_groups(cfgs, pad_jobs)
+    for g in groups:
+        g.cfg = _bound_record(g.cfg, group_sweep(cfgs, overrides, g))
     return points, cfgs, overrides, groups
 
 
@@ -1000,13 +1025,15 @@ def run_plan(plan: Plan, *, shard="auto", pad_jobs: bool = True,
                     per_point = [_point_params(cfgs[i], overrides[i], group)
                                  for i in idxs]
                     sweep = _stack_params(per_point)
+                    group.cfg = _bound_record(group.cfg, sweep)
                     sweep, _ = _shard_sweep(sweep, k, shard)
                 prof = GroupProfile(n_points=k, n_jobs=group.cfg.jobs.n_jobs,
                                     n_flows=group.cfg.topo.n_flows,
                                     n_ticks=group.cfg.n_ticks,
                                     wall_s=0.0, traced=False,
                                     signature=_group_signature(group),
-                                    stack_s=stack.seconds)
+                                    stack_s=stack.seconds,
+                                    iter_slots=group.cfg.max_iters_recorded)
                 with counters.watch() as w, counters.span(
                         "run_plan.device", group=gi, points=k) as device:
                     raw = simulate_sweep(group.cfg, sweep)

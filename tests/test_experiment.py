@@ -353,3 +353,193 @@ def test_cache_key_rejects_object_leaves():
     with pytest.raises(TypeError, match="object"):
         experiment._point_cache_key(
             cfg, {"x": np.array([object(), object()], dtype=object)})
+
+
+# ---------------------------------------------------------------------------
+# The iteration record sized to the group's bound
+# ---------------------------------------------------------------------------
+
+def _same_bytes(a, b, path="result") -> None:
+    """Assert two results equal leaf by leaf, byte for byte (NaN payloads
+    and signed zeros included)."""
+    if dataclasses.is_dataclass(a) and not isinstance(a, type):
+        assert type(a) is type(b), path
+        for f in dataclasses.fields(a):
+            _same_bytes(getattr(a, f.name), getattr(b, f.name),
+                        f"{path}.{f.name}")
+    elif isinstance(a, dict):
+        assert a.keys() == b.keys(), path
+        for k in a:
+            _same_bytes(a[k], b[k], f"{path}[{k!r}]")
+    elif isinstance(a, (list, tuple)):
+        assert type(a) is type(b) and len(a) == len(b), path
+        for i, (x, y) in enumerate(zip(a, b)):
+            _same_bytes(x, y, f"{path}[{i}]")
+    elif a is None or b is None:
+        assert a is None and b is None, path
+    else:
+        x, y = np.asarray(a), np.asarray(b)
+        assert x.dtype == y.dtype and x.shape == y.shape, path
+        assert x.tobytes() == y.tobytes(), path
+
+
+def _record_plan_dumbbell():
+    """Reno, a padded job-count group: 2 and 3 jobs on the 3-job fabric."""
+    def build(pt):
+        n = pt["n_jobs"]
+        topo = netsim.dumbbell(n, sockets_per_job=2)
+        jobs = netsim.JobSpec.simple([0.003, 0.0045, 0.006][:n],
+                                     [2e6, 3e6, 1e6][:n])
+        return netsim.SimConfig(topo=topo, jobs=jobs, protocol=_proto(),
+                                sim_time=0.06, dt=DT, seed=0)
+    return netsim.Plan(name="record-dumbbell", build=build,
+                       axes=(netsim.Axis("n_jobs", (2, 3)),
+                             netsim.Axis("seed", (4, 5))))
+
+
+def _record_plan_triangle():
+    """DCQCN on three links, a two-phase program, stragglers and an armed
+    straggle-burst channel."""
+    spec = netsim.FaultSpec(n_events=3, straggle_bursts=True)
+    jobs = netsim.JobSpec(
+        compute=np.array([[0.002, 0.001], [0.004, 0.0], [0.003, 0.0]]),
+        comm_bytes=np.array([[1e6, 2e6], [2e6, 0.0], [3e6, 0.0]]),
+        n_phases=np.array([2, 1, 1], np.int32),
+        start_offset=np.array([0.0, 0.001, 0.0]),
+        straggle_prob=np.array([0.2, 0.0, 0.1]),
+        iso_iter_time=np.array([0.0035, 0.0043, 0.0035]))
+
+    def build(pt):
+        return netsim.SimConfig(
+            topo=netsim.triangle(2), jobs=jobs,
+            protocol=_proto(algo=Algo.DCQCN), sim_time=0.05, dt=DT,
+            seed=0, faults=spec)
+    return netsim.Plan(
+        name="record-triangle", build=build,
+        axes=(netsim.Axis("burst", (0.0, 0.5), field="*", resolve=(
+            lambda p: lambda cfg: netsim.fault_schedule(
+                cfg, [netsim.straggle_burst(0.01, 0.03, p)],
+                spec=spec).overrides())),
+              netsim.Axis("seed", (1, 2))))
+
+
+def _record_plan_two_tier():
+    """Reno on the two-tier fabric, with a job leaving and re-joining
+    mid-run (churn) beside a straggle burst."""
+    spec = netsim.FaultSpec(n_events=4, churn=True, straggle_bursts=True)
+
+    def build(pt):
+        topo = netsim.two_tier([(0, 1), (1, 2), (2, 0)], n_leaves=3)
+        jobs = netsim.JobSpec.simple([0.002, 0.003, 0.0025],
+                                     [2e6, 1.5e6, 2.5e6])
+        return netsim.SimConfig(topo=topo, jobs=jobs, protocol=_proto(),
+                                sim_time=0.05, dt=DT, seed=0, faults=spec)
+    events = {"none": [],
+              "churn": [netsim.job_departs(0.0105, 1),
+                        netsim.job_arrives(0.02, 1),
+                        netsim.straggle_burst(0.03, None, 0.3)]}
+    return netsim.Plan(
+        name="record-two-tier", build=build,
+        axes=(netsim.Axis("events", tuple(events), field="*", resolve=(
+            lambda name: lambda cfg: netsim.fault_schedule(
+                cfg, events[name], spec=spec).overrides())),
+              netsim.Axis("seed", (6, 7))))
+
+
+# 127 iterations of 2 ticks fit 254 ticks: the bound is 128, one slot above
+TIGHT_TICKS = 254
+
+
+def _record_plan_tight():
+    """Compute of 1.5 ticks and no bytes: every iteration takes 2 ticks,
+    so the jobs finish within a slot of the bound."""
+    def build(pt):
+        jobs = netsim.JobSpec.simple([1.5 * DT] * 2, [0.0] * 2)
+        return netsim.SimConfig(topo=netsim.dumbbell(2, sockets_per_job=2),
+                                jobs=jobs, protocol=_proto(),
+                                sim_time=TIGHT_TICKS * DT, dt=DT, seed=0)
+    return netsim.Plan(name="record-tight", build=build,
+                       axes=(netsim.Axis("seed", (0, 1)),))
+
+
+RECORD_PLANS = {"dumbbell": _record_plan_dumbbell,
+                "triangle": _record_plan_triangle,
+                "two_tier": _record_plan_two_tier,
+                "tight": _record_plan_tight}
+
+
+@pytest.mark.parametrize("case", sorted(RECORD_PLANS))
+def test_bounded_record_matches_full_record_bytewise(case, monkeypatch):
+    """A plan run with its record sized to the group's bound gives every
+    `SimResult` field byte for byte as with the record at its 4,096-slot
+    ceiling, and no job finishes more iterations than the bound."""
+    plan = RECORD_PLANS[case]()
+    _, cfgs, overrides, groups = experiment.resolve_plan(plan)
+    bounds = []
+    for g in groups:
+        sweep = experiment.group_sweep(cfgs, overrides, g)
+        active = (None if sweep.job_active is None
+                  else np.asarray(sweep.job_active))
+        bounds.append(engine.iteration_bound(g.cfg, np.asarray(sweep.compute),
+                                             active))
+    bounded = netsim.run_plan(plan, shard=False)
+    slots = [g.iter_slots for g in bounded.profile.groups]
+    assert slots == [g.cfg.max_iters_recorded for g in groups]
+    assert all(b <= s < engine.MAX_ITERS for b, s in zip(bounds, slots))
+    most = max(len(x) for r in bounded for x in r.iter_times)
+    assert 2 <= most <= max(bounds)
+    if case == "tight":
+        assert most == TIGHT_TICKS // 2 and max(bounds) - most <= 2
+    monkeypatch.setattr(experiment, "_bound_record", lambda cfg, sweep: cfg)
+    full = netsim.run_plan(plan, shard=False)
+    assert ([g.iter_slots for g in full.profile.groups]
+            == [engine.MAX_ITERS] * len(groups))
+    assert len(bounded) == len(full)
+    for a, b in zip(bounded, full):
+        _same_bytes(a, b)
+
+
+def _bench_like_plan(orders, seeds):
+    """Distinct single-phase programs, permuted across the jobs per point,
+    each point with its own seed, OFF and WI (as the benchmark's calls)."""
+    compute = np.array([0.004, 0.0025, 0.006])
+    comm = np.array([3e6, 2e6, 4e6])
+
+    def build(pt):
+        order = list(orders[pt["point"]])
+        variant = {"OFF": Variant.OFF, "WI": Variant.WI}[pt["variant"]]
+        return netsim.SimConfig(
+            topo=netsim.dumbbell(3, sockets_per_job=2),
+            jobs=netsim.JobSpec.simple(compute[order], comm[order]),
+            protocol=_proto(variant=variant), sim_time=0.0413, dt=DT,
+            seed=seeds[pt["point"]])
+    return netsim.Plan(
+        name="record-bench-like", build=build,
+        axes=(netsim.Axis("variant", ("OFF", "WI"), kind="static"),
+              netsim.Axis("point", tuple(range(len(orders))),
+                          kind="static")))
+
+
+def test_bounded_record_does_not_retrace_on_reordered_programs():
+    """The bound reads the programs, not their order or the seeds: a re-run
+    with the programs permuted and new seeds traces nothing and sizes the
+    record alike.  The record-form counter reads "bounded" for the plan and
+    "default" for a direct `simulate_sweep` call."""
+    from repro.netsim import counters
+
+    with counters.watch() as w:
+        first = netsim.run_plan(_bench_like_plan(
+            [(0, 1, 2), (2, 0, 1)], [11, 12]), shard=False)
+    assert w.traces == 2
+    assert w.iter_records == {"bounded": 2, "default": 0}
+    before = engine.TRACE_COUNT
+    again = netsim.run_plan(_bench_like_plan(
+        [(1, 2, 0), (0, 2, 1)], [2**31 - 5, 987654321]), shard=False)
+    assert engine.TRACE_COUNT == before
+    slots = [g.iter_slots for g in first.profile.groups]
+    assert [g.iter_slots for g in again.profile.groups] == slots
+    assert all(1 < s < engine.MAX_ITERS for s in slots)
+    cfg = _cfg(sim_time=0.0413)
+    with counters.watch() as w2:
+        netsim.simulate_sweep(cfg, netsim.make_sweep(cfg, seed=[1, 2]))
+    assert w2.iter_records == {"bounded": 0, "default": 1}

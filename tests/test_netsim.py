@@ -1,4 +1,5 @@
 """Netsim engine invariants + the paper's headline system behaviours."""
+import dataclasses
 import re
 
 import jax.numpy as jnp
@@ -250,3 +251,76 @@ def test_route_counter_reads_each_programs_form():
             engine.trace_sweep(cfg, netsim.make_sweep(cfg))
     assert w.traces == 2
     assert w.routes == {"single_hop": 1, "select": 1}
+
+
+# ---------------------------------------------------------------------------
+# The iteration record
+# ---------------------------------------------------------------------------
+
+def _countdown_ticks(compute: np.ndarray) -> np.ndarray:
+    """Ticks the phase machine's float32 countdown ``t_rem -= dt`` takes
+    from each compute value to reach zero (at least one)."""
+    x = compute.astype(np.float32)
+    d = np.float32(DT)
+    ticks = np.zeros(x.shape, np.int64)
+    live = np.ones(x.shape, bool)
+    while live.any():
+        x = np.where(live, x - d, x)
+        ticks += live
+        live &= x > 0
+    return ticks
+
+
+def test_iteration_bound_holds_for_float32_countdown():
+    """For compute values from a fraction of a tick to 1.2 s (where float32
+    drift over 60,000 steps is largest), exact multiples of the tick and
+    values an ulp either side, the bound is never below the iterations a
+    job finishes when each takes exactly its countdown, which float32 drift
+    can end before ``c / dt`` ticks."""
+    rng = np.random.default_rng(3)
+    steps = np.concatenate([rng.uniform(0.1, 60_000.0, 150),
+                            np.arange(1.0, 40.0), [1125.0, 4999.0, 50_000.0]])
+    base = (steps * DT).astype(np.float32)
+    compute = np.concatenate([base, np.nextafter(base, np.float32(0)),
+                              np.nextafter(base, np.float32(1))])
+    true = _countdown_ticks(compute)
+    # the float32 countdown ends up to ~45 ticks before c / dt at 1.2 s
+    assert np.any(true < np.ceil(compute.astype(np.float64) / np.float32(DT)))
+    topo, iters = netsim.dumbbell(1), 10_000
+    for c, n in zip(compute, true):
+        cfg = netsim.SimConfig(
+            topo=topo, jobs=netsim.JobSpec.simple([c], [0.0]),
+            protocol=_proto(), sim_time=float(iters * n) * DT, dt=DT)
+        assert engine.iteration_bound(cfg, np.array([[c]])) >= iters, (c, n)
+
+
+def test_iteration_bound_reads_phases_mask_and_churn():
+    """The bound sums a job's live phases, leaves out masked jobs, and
+    gives each churn event one iteration more."""
+    jobs = netsim.JobSpec(compute=np.array([[10 * DT, 20 * DT],
+                                            [5 * DT, 99.0]]),
+                          comm_bytes=np.zeros((2, 2)),
+                          n_phases=np.array([2, 1], np.int32),
+                          start_offset=np.zeros(2), straggle_prob=np.zeros(2),
+                          iso_iter_time=np.ones(2))
+    cfg = netsim.SimConfig(topo=netsim.dumbbell(2), jobs=jobs,
+                           protocol=_proto(), sim_time=300 * DT, dt=DT)
+    compute = np.asarray(jobs.compute)
+    assert engine.iteration_bound(cfg, compute) == 300 // 5 + 1
+    assert engine.iteration_bound(cfg, compute,
+                                  np.array([True, False])) == 300 // 30 + 1
+    churned = dataclasses.replace(
+        cfg, faults=netsim.FaultSpec(n_events=3, churn=True))
+    assert engine.iteration_bound(churned, compute) == 300 // 5 + 1 + 3
+
+
+def test_compiled_record_write_is_no_scatter():
+    """The compiled sweep program writes the iteration record with a
+    select: no scatter or gather op takes or gives the record's shape."""
+    cfg = _fabric_cfg(netsim.dumbbell(2, sockets_per_job=2), sim_time=0.002)
+    text = engine.lower_sweep(cfg, netsim.make_sweep(cfg, seed=[0, 1])) \
+        .compile().as_text()
+    record = f"[2,2,{engine.MAX_ITERS}]"
+    assert record in text
+    assert not [line for line in text.splitlines() if record in line
+                and re.search(r"\s(scatter|gather)\(", line)]
