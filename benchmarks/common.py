@@ -100,16 +100,15 @@ def plan(build, *, name: str = "", where=None, **axes) -> netsim.Plan:
                        where=where)
 
 
-# Per-suite fusion/cache health, accumulated across every plan a suite runs
+# Per-suite fusion health, accumulated across every plan a suite runs
 # (suites may run several); `timed` resets it per benchmark and attaches the
 # totals to the BenchResult so run.py can print + merge them.  The last
 # three keys are the static analyzer's verdict: compile groups the plan
 # lint predicted before the run, plans whose executed group count diverged
 # from that prediction, and non-info plan-lint findings (avoidable splits).
-_PLAN_HEALTH = {"n_kernel_fallbacks": 0, "n_cache_hits": 0,
-                "n_compile_groups": 0, "n_groups_predicted": 0,
-                "n_group_mispredicts": 0, "n_plan_findings": 0,
-                "n_group_errors": 0}
+_PLAN_HEALTH = {"n_kernel_fallbacks": 0, "n_compile_groups": 0,
+                "n_groups_predicted": 0, "n_group_mispredicts": 0,
+                "n_plan_findings": 0, "n_group_errors": 0}
 
 
 def reset_plan_health() -> None:
@@ -123,7 +122,7 @@ def plan_health() -> dict:
 
 def run_plan(p: netsim.Plan, **kw) -> netsim.PlanResult:
     """Execute a plan (thin wrapper so suites share one entry point and
-    their fusion/cache health aggregates per suite).
+    their fusion health aggregates per suite).
 
     Each execution is preceded by the plan lint: the predicted compile
     groups and any non-info findings land in the suite's health block, and
@@ -140,7 +139,6 @@ def run_plan(p: netsim.Plan, **kw) -> netsim.PlanResult:
 
     pr = netsim.run_plan(p, **kw)
     _PLAN_HEALTH["n_kernel_fallbacks"] += pr.n_kernel_fallbacks
-    _PLAN_HEALTH["n_cache_hits"] += pr.n_cache_hits
     _PLAN_HEALTH["n_compile_groups"] += pr.n_compile_groups
     # keep_going=True salvage: failed compile groups land here instead of
     # aborting the suite; a nonzero count in _health flags the partial run
@@ -201,7 +199,7 @@ class BenchResult:
     wall_s: float
     n_ticks: int
     derived: dict
-    # fusion/cache health over every plan the suite ran (plan_health())
+    # fusion health over every plan the suite ran (plan_health())
     health: dict = dataclasses.field(default_factory=dict)
 
     def csv_line(self) -> str:
@@ -209,8 +207,7 @@ class BenchResult:
         key, val = next(iter(self.derived.items()))
         line = f"{self.name},{us:.3f},{key}={val}"
         if self.health:
-            line += (f",fallbacks={self.health.get('n_kernel_fallbacks', 0)}"
-                     f",cache_hits={self.health.get('n_cache_hits', 0)}")
+            line += f",fallbacks={self.health.get('n_kernel_fallbacks', 0)}"
         return line
 
 

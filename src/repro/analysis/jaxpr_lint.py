@@ -51,20 +51,17 @@ _F64 = "float64"
 def kernel_expectation(cfg, sweep) -> str:
     """What the lowering *must* contain: "fused" | "fallback" | "off".
 
-    Mirrors the static fallback decision in ``kernels.ops.mltcp_cc_tick``
-    (and nothing else — that's the point: if ops.py and this function ever
-    disagree, the kernel-missing / kernel-unexpected rules catch it on the
-    next lint run).
+    The kernel's eligibility is ``kernels.ops.cc_tick_fallback_reason``,
+    the function `mltcp_cc_tick` dispatches on; Static-baseline factors on
+    the sweep ride in as operands.
     """
+    from repro.kernels import ops
+
     if not cfg.use_pallas_kernel:
         return "off"
-    if sweep.static_job_factors is not None:
-        # Static-baseline factors ride in as operands; favoritism/F moot.
-        return "fused"
-    proto = cfg.protocol
-    if proto.favoritism != "largest_data_sent" or proto.f_spec != "linear":
-        return "fallback"
-    return "fused"
+    reason = ops.cc_tick_fallback_reason(
+        cfg.protocol, sweep.static_job_factors is not None)
+    return "fused" if reason is None else "fallback"
 
 
 @dataclasses.dataclass

@@ -200,6 +200,33 @@ def _pack(x, n_pad, fill=0.0, dtype=jnp.float32):
     return x.reshape(n_pad // ms.LANES, ms.LANES)
 
 
+def cc_tick_fallback_reason(cfg: core.MLTCPConfig,
+                            static_factors: bool) -> Optional[str]:
+    """Why the fused kernel cannot run the CC tick of ``cfg``, or None.
+
+    The one owner of the kernel's specialization: `mltcp_cc_tick` falls
+    back on it, the experiment layer splits compile groups on it
+    (``experiment._factors_need_split``) and the IR lint predicts the
+    lowering from it (``analysis.jaxpr_lint.kernel_expectation``).
+
+    Static [67] factors replace F(score) per flow (negative entries are
+    the "adaptive" sentinel — see core.cc_tick), so with factors present
+    favoritism/f_spec are moot and must not force a fallback for a
+    Static-baseline arm of an ablation plan.  Sentinel entries reuse the
+    kernel's adaptive branch, which implements only the default linear F
+    over largest_data_sent; the experiment layer therefore never merges
+    Static and adaptive points into one kernel-enabled group unless this
+    returns None without factors.
+    """
+    if static_factors:
+        return None
+    if cfg.favoritism != "largest_data_sent":
+        return f"favoritism={cfg.favoritism!r}"
+    if cfg.f_spec != "linear":
+        return f"f_spec={cfg.f_spec!r}"
+    return None
+
+
 def mltcp_cc_tick(cfg: core.MLTCPConfig, state: core.MLTCPState,
                   fb: core.Feedback, total_bytes: Array,
                   flow_to_job: Optional[Array] = None, n_jobs: int = 0,
@@ -220,20 +247,7 @@ def mltcp_cc_tick(cfg: core.MLTCPConfig, state: core.MLTCPState,
     the jnp oracle; the fallback is loud (``FALLBACK_COUNT`` + one-time
     warning) so ``use_pallas_kernel=True`` can never silently run unfused.
     """
-    # Static [67] factors replace F(score) per flow (negative entries are
-    # the "adaptive" sentinel — see core.cc_tick), so with all-non-negative
-    # factors favoritism/f_spec are moot and must not force a fallback for
-    # a Static-baseline arm of an ablation plan.  Sentinel entries reuse
-    # the kernel's adaptive branch, which implements only the default
-    # linear F over largest_data_sent; the experiment layer therefore
-    # never merges Static and adaptive points into one kernel-enabled
-    # group unless that default applies (experiment._compile_groups).
-    reason = None
-    if static_factors is None:
-        if cfg.favoritism != "largest_data_sent":
-            reason = f"favoritism={cfg.favoritism!r}"
-        elif cfg.f_spec != "linear":
-            reason = f"f_spec={cfg.f_spec!r}"
+    reason = cc_tick_fallback_reason(cfg, static_factors is not None)
     if reason is not None:
         global FALLBACK_COUNT
         FALLBACK_COUNT += 1

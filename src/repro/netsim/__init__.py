@@ -10,8 +10,8 @@ static *and* dynamic axes and lowers them onto that sweep axis, one compile
 group per distinct static signature.  Workload *values* — phase programs,
 straggle probabilities, Cassini schedules, Static factors — are traced
 sweep leaves, so straggler/compat grids fold into one group per variant;
-job-count grids pad + mask into a single group; K optionally shards across
-local devices; and `run_plan(..., cache_dir=)` makes runs resumable.
+job-count grids pad + mask into a single group; and K optionally shards
+across local devices.
 """
 
 from repro.netsim.topology import Topology, dumbbell, triangle, two_tier
@@ -36,7 +36,6 @@ from repro.netsim.experiment import (
     Plan,
     PlanProfile,
     PlanResult,
-    prune_cache,
     restrict_workload,
     run_plan,
 )
@@ -78,7 +77,7 @@ __all__ = [
     "SweepParams", "SweepPoint", "simulate_sweep", "make_sweep",
     "grid_sweep", "sweep_len", "sweep_of", "sweep_slice",
     "Axis", "Plan", "PlanResult", "GroupError", "GroupProfile",
-    "PlanProfile", "prune_cache", "restrict_workload", "run_plan",
+    "PlanProfile", "restrict_workload", "run_plan",
     "FaultSpec", "FaultEvent", "FaultSchedule", "fault_schedule",
     "identity_schedule", "job_arrives", "job_departs", "link_flap",
     "blackhole", "straggle_burst",

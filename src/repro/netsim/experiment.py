@@ -44,20 +44,10 @@ and `run_plan` does the partitioning (DESIGN.md §5):
 A Fig. 10-style plan (7 job counts x 3 seeds x {OFF, WI}) thus compiles
 *two* programs (one per variant) instead of 14+, and the straggler /
 partial-compat grids (which sweep workload values) collapse the same way.
-
-``run_plan(..., cache_dir=...)`` adds a SweepPoint-keyed on-disk cache:
-each point's result is stored under a content hash of its full config and
-resolved dynamic overrides, so interrupted benchmark runs resume and
-figures re-aggregate without re-simulating.
 """
 from __future__ import annotations
 
 import dataclasses
-import hashlib
-import math
-import os
-import pickle
-import warnings
 from typing import Callable, Optional
 
 import jax
@@ -79,7 +69,7 @@ from repro.netsim.telemetry import TelemetrySpec
 from repro.netsim.topology import Topology
 
 __all__ = ["Axis", "Plan", "PlanResult", "GroupError", "GroupProfile",
-           "PlanProfile", "run_plan", "prune_cache", "restrict_workload",
+           "PlanProfile", "run_plan", "restrict_workload",
            "resolve_plan", "group_sweep"]
 
 _DYNAMIC_FIELDS = frozenset(SweepParams._fields)
@@ -292,16 +282,17 @@ def _factors_need_split(cfg: SimConfig) -> bool:
     """True when Static-factor presence may not be mixed in one group.
 
     The fused kernel's adaptive branch (which sentinel factor entries
-    select) implements only the default linear F over largest_data_sent;
-    under any other structural option a kernel-enabled group must keep
-    factor-bearing and adaptive points apart so no sentinel ever reaches
-    the kernel (pure-Static groups stay fused — all entries >= 0 mask the
-    branch exactly).  The jnp oracle computes the true adaptive F, so
-    non-kernel configs always mix.
+    select) runs only where the kernel would run the config without
+    factors (`ops.cc_tick_fallback_reason`); otherwise a kernel-enabled
+    group must keep factor-bearing and adaptive points apart so no
+    sentinel ever reaches the kernel (pure-Static groups stay fused — all
+    entries >= 0 mask the branch exactly).  The jnp oracle computes the
+    true adaptive F, so non-kernel configs always mix.
     """
-    return cfg.use_pallas_kernel and (
-        cfg.protocol.f_spec != "linear"
-        or cfg.protocol.favoritism != "largest_data_sent")
+    if not cfg.use_pallas_kernel:
+        return False
+    from repro.kernels import ops
+    return ops.cc_tick_fallback_reason(cfg.protocol, False) is not None
 
 
 @dataclasses.dataclass
@@ -549,8 +540,7 @@ class GroupProfile:
     (``stack_s``, ``run_plan.stack``), the device call with its
     ``block_until_ready`` (``wall_s``, ``run_plan.device``; trace and
     compile included when the group traced a new program, ``traced``) and
-    the per-point postprocess and cache save (``postprocess_s``,
-    ``run_plan.postprocess``).  ``iter_slots`` is the length of the
+    the per-point postprocess (``postprocess_s``, ``run_plan.postprocess``).  ``iter_slots`` is the length of the
     group's iteration record.
     """
 
@@ -571,7 +561,7 @@ class PlanProfile:
     """Per-group runtime profiles of one `run_plan` call.
 
     ``prepare_s`` is the call's ``run_plan.prepare`` span (points, builds,
-    overrides, cache lookups, grouping); ``jit_s`` the call's
+    overrides, grouping); ``jit_s`` the call's
     `counters.jit_seconds` deltas (trace, lower, compile, cache_load).
     The costing input for scheduling follow-ons (ROADMAP: sharding
     *across* compile groups needs per-group cost estimates).
@@ -650,9 +640,6 @@ class PlanResult:
     # groups are already in the jit cache reports 0 — read it off the first
     # run of a given static config.
     n_kernel_fallbacks: int = 0
-    # points served from run_plan's cache_dir (0 without a cache);
-    # n_compile_groups counts only the groups actually simulated.
-    n_cache_hits: int = 0
     # host seconds of the call's phases, per group, and its jit seconds
     profile: PlanProfile = dataclasses.field(default_factory=PlanProfile)
     # compile groups that failed under keep_going=True (empty otherwise —
@@ -691,195 +678,6 @@ class PlanResult:
     def n_ticks(self) -> int:
         """Total simulator ticks executed (for µs/tick accounting)."""
         return sum(r.cfg.n_ticks for r in self.results if r is not None)
-
-
-# ---------------------------------------------------------------------------
-# On-disk point cache (resumable benchmark runs)
-# ---------------------------------------------------------------------------
-
-# Array dtype kinds the cache key encodes bit-for-bit.  Everything else —
-# object arrays most importantly — is rejected loudly: ``tobytes()`` on an
-# object array serializes *pointers*, which are unique per process, so a
-# silently-coerced leaf would make every run a cache miss (or worse, a
-# collision if the allocator reuses addresses).
-_HASHABLE_KINDS = frozenset("biufcSU")  # bool/int/uint/float/complex/bytes/str
-
-
-def _canonical_float_array(a: np.ndarray) -> np.ndarray:
-    """Float arrays with every NaN rewritten to the canonical quiet NaN.
-
-    IEEE NaNs carry payload/sign bits that `tobytes` would leak into the
-    key: two logically-identical configs built via different code paths
-    (e.g. 0/0 vs float("nan")) could hash apart and silently re-simulate.
-    Distinct *positions* of NaN still produce distinct keys — only the
-    bit-pattern within each NaN is normalized.
-    """
-    if a.dtype.kind not in "fc" or not np.isnan(a).any():
-        return a
-    a = a.copy()
-    a[np.isnan(a)] = np.nan
-    return a
-
-
-def _stable_bytes(obj, out: list) -> None:
-    """Deterministic byte serialization for cache keys (hash() is salted
-    per process, so HashableConfig hashes cannot key an on-disk cache).
-
-    Non-finite floats are encoded explicitly (every NaN bit-pattern maps to
-    one token; +/-inf keep their signs) and array leaves must be of a
-    plainly-hashable dtype — anything that numpy would coerce to an object
-    array raises instead of producing a pointer-dependent key.
-    """
-    if obj is None or isinstance(obj, (bool, int, str)):
-        out.append(repr(obj).encode())
-    elif isinstance(obj, float):
-        if math.isnan(obj):
-            out.append(b"f:nan")
-        elif math.isinf(obj):
-            out.append(b"f:+inf" if obj > 0 else b"f:-inf")
-        else:
-            out.append(np.float64(obj).tobytes())
-    elif isinstance(obj, np.ndarray):
-        if obj.dtype.kind not in _HASHABLE_KINDS:
-            raise TypeError(
-                f"cache key leaf is a {obj.dtype} array; only "
-                f"bool/int/float/complex/str arrays have a stable byte "
-                f"encoding (object arrays would hash their pointers)")
-        out.append(f"nd{obj.dtype}{obj.shape}".encode())
-        out.append(np.ascontiguousarray(_canonical_float_array(obj))
-                   .tobytes())
-    elif isinstance(obj, (list, tuple)):
-        out.append(f"seq{len(obj)}".encode())
-        for v in obj:
-            _stable_bytes(v, out)
-    elif isinstance(obj, dict):
-        out.append(f"map{len(obj)}".encode())
-        for k in sorted(obj):
-            _stable_bytes(k, out)
-            _stable_bytes(obj[k], out)
-    elif dataclasses.is_dataclass(obj):
-        out.append(type(obj).__name__.encode())
-        for f in dataclasses.fields(obj):
-            _stable_bytes(f.name, out)
-            _stable_bytes(getattr(obj, f.name), out)
-    else:
-        arr = np.asarray(obj)
-        if arr.dtype.kind not in _HASHABLE_KINDS:
-            raise TypeError(
-                f"cache key leaf of type {type(obj).__name__} has no "
-                f"stable byte encoding (coerces to a {arr.dtype} array)")
-        _stable_bytes(arr, out)
-
-
-# Result-schema version: bump whenever the pickled `SimResult` payload
-# changes shape (new fields, changed semantics).  It salts the content hash
-# AND prefixes the filename, so entries written under another schema are
-# never deserialized — they simply miss — and `prune_cache` can evict them
-# by name without unpickling anything.
-_SCHEMA_VERSION = 2
-
-
-def _point_cache_key(cfg: SimConfig, overrides: dict) -> str:
-    """Content hash of everything that determines one point's result: the
-    result-schema version, the point's full (uncanonicalized) config and
-    its resolved dynamic overrides.  Deliberately *not* a function of the
-    group the point lands in — padded lowering is value-identical to
-    unpadded (DESIGN.md §5), so cached results survive regrouping (new
-    axis values, pad_jobs toggles).
-    """
-    out: list = [f"repro-plan-cache-v{_SCHEMA_VERSION}".encode()]
-    _stable_bytes(cfg, out)
-    _stable_bytes({k: np.asarray(v) for k, v in overrides.items()}, out)
-    return hashlib.sha256(b"".join(out)).hexdigest()[:32]
-
-
-def _cache_path(cache_dir: str, key: str) -> str:
-    return os.path.join(cache_dir, f"v{_SCHEMA_VERSION}-{key}.pkl")
-
-
-def prune_cache(cache_dir: str) -> int:
-    """Evict cache entries written under a different result-schema version.
-
-    Stale-version entries are already unreachable (the version salts the
-    key and prefixes the filename), so this only reclaims disk; returns the
-    number of files removed.  Unversioned `.pkl` files (the v1 layout),
-    torn `.tmp` leftovers, quarantined ``*.corrupt`` entries and zero-byte
-    current-version entries (a crash between `open` and the first write of
-    some other tool — `_cache_save` itself is atomic) are pruned too;
-    healthy current-version entries are kept.
-    """
-    prefix = f"v{_SCHEMA_VERSION}-"
-    removed = 0
-    try:
-        names = os.listdir(cache_dir)
-    except OSError:
-        return 0
-    for name in names:
-        path = os.path.join(cache_dir, name)
-        stale_pkl = name.endswith(".pkl") and not name.startswith(prefix)
-        zero_byte = False
-        if name.endswith(".pkl") and not stale_pkl:
-            try:
-                zero_byte = os.path.getsize(path) == 0
-            except OSError:
-                pass
-        if (stale_pkl or name.endswith(".tmp") or name.endswith(".corrupt")
-                or zero_byte):
-            try:
-                os.remove(path)
-                removed += 1
-            except OSError:
-                pass
-    return removed
-
-
-# Corrupt-entry paths already warned about this process (warn once per
-# entry, not once per plan re-run).
-_QUARANTINE_WARNED: set = set()
-
-
-def _cache_load(cache_dir: str, key: str) -> Optional[metrics.SimResult]:
-    path = _cache_path(cache_dir, key)
-    try:
-        f = open(path, "rb")
-    except OSError:
-        return None         # missing: a plain cache miss
-    try:
-        with f:
-            if os.fstat(f.fileno()).st_size == 0:
-                raise pickle.UnpicklingError("zero-byte cache entry")
-            return pickle.load(f)
-    except Exception:
-        # Unreadable / truncated / schema-drifted entry: quarantine it
-        # (rename to *.corrupt, so the next resume of this plan doesn't
-        # trip over it again and `prune_cache` can reclaim it), warn once,
-        # and treat as a miss — a corrupt entry must never crash a
-        # resumable run.
-        try:
-            os.replace(path, path + ".corrupt")
-        except OSError:
-            pass
-        if path not in _QUARANTINE_WARNED:
-            _QUARANTINE_WARNED.add(path)
-            warnings.warn(
-                f"quarantined corrupt plan-cache entry {path} -> *.corrupt;"
-                f" the point will be re-simulated", RuntimeWarning)
-        return None
-
-
-def _cache_save(cache_dir: str, key: str, res: metrics.SimResult) -> None:
-    # numpy-normalize the attached params so unpickling never needs a
-    # live JAX device context
-    if res.point is not None and res.point.params is not None:
-        res = dataclasses.replace(
-            res, point=dataclasses.replace(
-                res.point, params=jax.tree_util.tree_map(
-                    np.asarray, res.point.params)))
-    path = _cache_path(cache_dir, key)
-    tmp = path + ".tmp"
-    with open(tmp, "wb") as f:
-        pickle.dump(res, f)
-    os.replace(tmp, path)   # atomic: a crash never leaves a torn entry
 
 
 # ---------------------------------------------------------------------------
@@ -927,6 +725,31 @@ def _resolve_overrides(plan: Plan, points: list[dict],
     return overrides
 
 
+def _prepare(plan: Plan, pad_jobs: bool,
+             telemetry: Optional[TelemetrySpec]
+             ) -> tuple[list[dict], list[SimConfig], list[dict], list[_Group]]:
+    """The plan's points, each point's built config (telemetry stamped on
+    if given), its resolved dynamic overrides, and the compile groups
+    (before `_stack_group` bounds their iteration records)."""
+    points = plan.points()
+    cfgs = [plan.build(dict(pt)) for pt in points]
+    if telemetry is not None:
+        cfgs = [dataclasses.replace(c, telemetry=telemetry) for c in cfgs]
+    overrides = _resolve_overrides(plan, points, cfgs)
+    return points, cfgs, overrides, _compile_groups(cfgs, pad_jobs)
+
+
+def _stack_group(cfgs: list[SimConfig], overrides: list[dict],
+                 group: _Group
+                 ) -> tuple[list[SweepParams], SweepParams, SimConfig]:
+    """One group's per-point params, their stacked sweep, and the group
+    config with its iteration record bounded (`_bound_record`)."""
+    per_point = [_point_params(cfgs[i], overrides[i], group)
+                 for i in group.idxs]
+    sweep = _stack_params(per_point)
+    return per_point, sweep, _bound_record(group.cfg, sweep)
+
+
 def resolve_plan(plan: Plan, *, pad_jobs: bool = True,
                  telemetry: Optional[TelemetrySpec] = None
                  ) -> tuple[list[dict], list[SimConfig], list[dict],
@@ -935,22 +758,17 @@ def resolve_plan(plan: Plan, *, pad_jobs: bool = True,
 
     Returns ``(points, cfgs, overrides, groups)``: the plan's label dicts,
     each point's built config (telemetry stamped on if given), its resolved
-    dynamic overrides, and the predicted compile groups (each group's
-    ``idxs`` index into ``points``/``cfgs``; its ``cfg`` has the iteration
-    record `run_plan` gives it, `_bound_record`).  This is exactly the
-    grouping a cache-less `run_plan` would execute — the static analyzer
-    (`repro.analysis`) lints these groups' lowerings before anything runs,
-    and benchmark health checks compare the prediction against what a run
-    actually compiled.
+    dynamic overrides, and the compile groups (each group's ``idxs`` index
+    into ``points``/``cfgs``; its ``cfg`` has the iteration record
+    `run_plan` gives it, `_bound_record`).  Both run the same `_prepare`
+    and `_stack_group`, so this is exactly the grouping `run_plan`
+    executes — the static analyzer (`repro.analysis`) lints these groups'
+    lowerings before anything runs, and benchmark health checks compare
+    the prediction against what a run actually compiled.
     """
-    points = plan.points()
-    cfgs = [plan.build(dict(pt)) for pt in points]
-    if telemetry is not None:
-        cfgs = [dataclasses.replace(c, telemetry=telemetry) for c in cfgs]
-    overrides = _resolve_overrides(plan, points, cfgs)
-    groups = _compile_groups(cfgs, pad_jobs)
+    points, cfgs, overrides, groups = _prepare(plan, pad_jobs, telemetry)
     for g in groups:
-        g.cfg = _bound_record(g.cfg, group_sweep(cfgs, overrides, g))
+        _, _, g.cfg = _stack_group(cfgs, overrides, g)
     return points, cfgs, overrides, groups
 
 
@@ -958,13 +776,10 @@ def group_sweep(cfgs: list[SimConfig], overrides: list[dict],
                 group: _Group) -> SweepParams:
     """One compile group's batched SweepParams, exactly as `run_plan` would
     stack it (point params resolved on the group fabric, K = len(idxs))."""
-    per_point = [_point_params(cfgs[i], overrides[i], group)
-                 for i in group.idxs]
-    return _stack_params(per_point)
+    return _stack_group(cfgs, overrides, group)[1]
 
 
 def run_plan(plan: Plan, *, shard="auto", pad_jobs: bool = True,
-             cache_dir: Optional[str] = None,
              telemetry: Optional[TelemetrySpec] = None,
              keep_going: bool = False) -> PlanResult:
     """Execute a plan: one `simulate_sweep` per compile group.
@@ -973,59 +788,34 @@ def run_plan(plan: Plan, *, shard="auto", pad_jobs: bool = True,
                devices (see `_shard_sweep`).
     pad_jobs:  merge workload-size variants into one padded + masked compile
                group where possible (disable to force exact grouping).
-    cache_dir: if given, a directory of per-point result pickles keyed by a
-               content hash of (schema version, point config, resolved
-               overrides).  Points already present are served from disk and
-               *excluded* from compile-group formation; fresh points are
-               written back after postprocessing.  Interrupted plans resume
-               where they stopped, and grown plans only simulate the new
-               cells; `prune_cache` evicts entries from older schemas.
     telemetry: arm the probe subsystem (netsim.telemetry) on every point:
                the spec is stamped onto each built config (joining its
-               static signature and cache key), and each `SimResult` gains
-               a `.telemetry` with the probe series and detector outputs.
+               static signature), and each `SimResult` gains a
+               `.telemetry` with the probe series and detector outputs.
                None leaves the built configs untouched — a build function
                may still arm points itself.
     keep_going: isolate per-group failures — a compile group that raises
                (bad config, OOM, compile error) is recorded on
                `PlanResult.group_errors` (its members' result slots stay
-               None) and the remaining groups still run and cache, so one
-               poisoned cell cannot torch a long benchmark run.  The
-               default (False) re-raises at the failing group, exactly the
-               pre-existing behavior.
+               None) and the remaining groups still run, so one poisoned
+               cell cannot torch a long benchmark run.  The default
+               (False) re-raises at the failing group.
     """
     group_errors: list[GroupError] = []
     with counters.watch(reset_warnings=True) as plan_watch:
         with counters.span("run_plan.prepare") as prepare:
-            points = plan.points()
-            cfgs = [plan.build(dict(pt)) for pt in points]
-            if telemetry is not None:
-                cfgs = [dataclasses.replace(c, telemetry=telemetry)
-                        for c in cfgs]
-            overrides = _resolve_overrides(plan, points, cfgs)
-
+            points, cfgs, overrides, groups = _prepare(plan, pad_jobs,
+                                                       telemetry)
             results: list[Optional[metrics.SimResult]] = [None] * len(points)
-            keys: list[Optional[str]] = [None] * len(points)
-            if cache_dir is not None:
-                os.makedirs(cache_dir, exist_ok=True)
-                for i in range(len(points)):
-                    keys[i] = _point_cache_key(cfgs[i], overrides[i])
-                    results[i] = _cache_load(cache_dir, keys[i])
-            n_cache_hits = sum(r is not None for r in results)
-            todo = [i for i in range(len(points)) if results[i] is None]
-
-            groups = _compile_groups([cfgs[i] for i in todo], pad_jobs)
         plan_profile = PlanProfile(prepare_s=prepare.seconds)
         for gi, group in enumerate(groups):
-            idxs = [todo[j] for j in group.idxs]  # group indexes todo subset
+            idxs = group.idxs
             k = len(idxs)
             try:
                 with counters.span("run_plan.stack", group=gi,
                                    points=k) as stack:
-                    per_point = [_point_params(cfgs[i], overrides[i], group)
-                                 for i in idxs]
-                    sweep = _stack_params(per_point)
-                    group.cfg = _bound_record(group.cfg, sweep)
+                    per_point, sweep, group.cfg = _stack_group(
+                        cfgs, overrides, group)
                     sweep, _ = _shard_sweep(sweep, k, shard)
                 prof = GroupProfile(n_points=k, n_jobs=group.cfg.jobs.n_jobs,
                                     n_flows=group.cfg.topo.n_flows,
@@ -1052,8 +842,6 @@ def run_plan(plan: Plan, *, shard="auto", pad_jobs: bool = True,
                         results[i] = metrics.postprocess(cfgs[i], raw_i,
                                                          point=point,
                                                          n_jobs=point.n_jobs)
-                        if cache_dir is not None:
-                            _cache_save(cache_dir, keys[i], results[i])
                 prof.postprocess_s = post.seconds
             except Exception as exc:
                 if not keep_going:
@@ -1068,6 +856,5 @@ def run_plan(plan: Plan, *, shard="auto", pad_jobs: bool = True,
     return PlanResult(plan=plan, results=results,
                       n_compile_groups=len(groups),
                       n_kernel_fallbacks=plan_watch.fallbacks,
-                      n_cache_hits=n_cache_hits,
                       profile=plan_profile,
                       group_errors=group_errors)

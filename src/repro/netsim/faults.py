@@ -180,8 +180,8 @@ class FaultSchedule:
 
     ``spec`` goes on the config (``dataclasses.replace(cfg, faults=s.spec)``)
     and ``overrides()`` feeds `make_sweep` / a plan's schedule axis — the
-    values are plain numpy, so they hash into the point cache key and stack
-    onto the batched sweep like any other dynamic leaf.
+    values are plain numpy, so they stack onto the batched sweep like any
+    other dynamic leaf.
     """
 
     spec: FaultSpec

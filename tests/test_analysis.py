@@ -82,6 +82,44 @@ def test_kernel_fallback_config_fires():
     assert facts["pallas_calls"] == 0        # and it really lowered unfused
 
 
+# (protocol options, Static factors, use_pallas_kernel, reason, lowering)
+_OTHER_FAV = {"favoritism": "smallest_data_remaining"}
+ELIGIBILITY = {
+    "default": ({}, False, True, None, "fused"),
+    "favoritism": (_OTHER_FAV, False, True,
+                   "favoritism='smallest_data_remaining'", "fallback"),
+    "f_spec": ({"f_spec": "F3"}, False, True, "f_spec='F3'", "fallback"),
+    "default+static": ({}, True, True, None, "fused"),
+    "favoritism+static": (_OTHER_FAV, True, True, None, "fused"),
+    "f_spec+static": ({"f_spec": "F3"}, True, True, None, "fused"),
+    "kernel_off": ({}, False, False, None, "off"),
+    "kernel_off+favoritism": (_OTHER_FAV, False, False,
+                              "favoritism='smallest_data_remaining'", "off"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ELIGIBILITY))
+def test_kernel_eligibility_has_one_owner(case):
+    """`ops.cc_tick_fallback_reason` decides the fused kernel's eligibility;
+    the group split and the IR lint's expectation agree with it."""
+    from repro.kernels import ops
+    from repro.netsim import experiment
+
+    proto_kw, factors, kernel, reason, lowering = ELIGIBILITY[case]
+    cfg = _cfg(use_pallas_kernel=kernel, protocol=_proto(**proto_kw))
+    if factors:
+        cfg = dataclasses.replace(cfg,
+                                  static_job_factors=np.array([1.0, 0.5]))
+    got = ops.cc_tick_fallback_reason(cfg.protocol, factors)
+    assert got == reason
+    assert kernel_expectation(cfg, engine.make_sweep(cfg)) == lowering
+    assert lowering == ("off" if not kernel
+                        else "fused" if got is None else "fallback")
+    bare = ops.cc_tick_fallback_reason(cfg.protocol, False)
+    assert experiment._factors_need_split(cfg) == (kernel
+                                                   and bare is not None)
+
+
 def test_armed_telemetry_lowering_clean():
     spec = netsim.TelemetrySpec(probes=("flow_cwnd", "link_queue"),
                                 stride=16)

@@ -6,6 +6,7 @@ padded + masked group whose active lanes match unpadded runs exactly, and
 every result is self-describing via its `SweepPoint`.
 """
 import dataclasses
+import warnings
 
 import numpy as np
 import pytest
@@ -148,30 +149,6 @@ def test_workload_values_merge_into_one_group():
             assert np.array_equal(ja, jb)
 
 
-def test_run_plan_cache_resumes(tmp_path):
-    """Satellite: SweepPoint-keyed on-disk cache makes plans resumable —
-    second run is all hits, a deleted entry re-simulates just that point,
-    and cached results are bit-identical to fresh ones."""
-    cache = str(tmp_path / "plan-cache")
-    plan = _jobs_plan(job_counts=(2, 3), seeds=(0, 1), sim_time=0.1,
-                      name="cached")
-    fresh = netsim.run_plan(plan, shard=False, cache_dir=cache)
-    assert fresh.n_cache_hits == 0 and fresh.n_compile_groups == 1
-    rerun = netsim.run_plan(plan, shard=False, cache_dir=cache)
-    assert rerun.n_cache_hits == len(rerun)
-    assert rerun.n_compile_groups == 0          # nothing left to simulate
-    for a, b in zip(fresh, rerun):
-        assert a.point.axes == b.point.axes
-        for ja, jb in zip(a.iter_times, b.iter_times):
-            assert np.array_equal(ja, jb)
-    # drop one entry -> exactly one point re-simulates
-    victims = sorted((tmp_path / "plan-cache").glob("*.pkl"))
-    victims[0].unlink()
-    partial = netsim.run_plan(plan, shard=False, cache_dir=cache)
-    assert partial.n_cache_hits == len(partial) - 1
-    assert partial.n_compile_groups == 1
-
-
 # ---------------------------------------------------------------------------
 # Axes: dynamic vs static, resolve, where
 # ---------------------------------------------------------------------------
@@ -306,53 +283,6 @@ def test_shard_auto_is_safe_on_any_device_count():
         np.testing.assert_allclose(np.concatenate(a.iter_times + [[0.0]]),
                                    np.concatenate(b.iter_times + [[0.0]]),
                                    rtol=1e-5, atol=1e-7)
-
-
-# ---------------------------------------------------------------------------
-# Cache-key hashing of non-finite / non-hashable leaves
-# ---------------------------------------------------------------------------
-
-def test_cache_key_nan_axes_do_not_collide():
-    """NaN-bearing override arrays must key by NaN *position*, not collapse
-    to one entry (the would-be cache aliasing bug) — and identical content
-    must still key identically."""
-    cfg = _cfg()
-    a = np.array([np.nan, 1.0, 2.0])
-    b = np.array([1.0, np.nan, 2.0])
-    k_a = experiment._point_cache_key(cfg, {"x": a})
-    k_b = experiment._point_cache_key(cfg, {"x": b})
-    assert k_a != k_b
-    assert k_a == experiment._point_cache_key(cfg, {"x": a.copy()})
-
-
-def test_cache_key_nan_bit_patterns_canonicalize():
-    """Two logically-identical configs whose NaNs carry different IEEE
-    payload bits (0/0 vs float('nan') vs payload-poked) must share a key."""
-    cfg = _cfg()
-    a = np.array([np.nan, 3.0])
-    b = a.copy()
-    b.view(np.uint64)[0] |= 0xDEAD          # poke payload bits, still NaN
-    assert np.isnan(b[0]) and a.tobytes() != b.tobytes()
-    assert (experiment._point_cache_key(cfg, {"x": a})
-            == experiment._point_cache_key(cfg, {"x": b}))
-    # python-float NaN leaves canonicalize the same way
-    assert (experiment._point_cache_key(cfg, {"x": float("nan")})
-            == experiment._point_cache_key(cfg, {"x": np.float64("nan")}))
-
-
-def test_cache_key_inf_signs_distinct():
-    cfg = _cfg()
-    assert (experiment._point_cache_key(cfg, {"x": float("inf")})
-            != experiment._point_cache_key(cfg, {"x": float("-inf")}))
-
-
-def test_cache_key_rejects_object_leaves():
-    """Object arrays hash their element pointers — nondeterministic across
-    processes — so they must raise instead of producing a silent bad key."""
-    cfg = _cfg()
-    with pytest.raises(TypeError, match="object"):
-        experiment._point_cache_key(
-            cfg, {"x": np.array([object(), object()], dtype=object)})
 
 
 # ---------------------------------------------------------------------------
@@ -543,3 +473,54 @@ def test_bounded_record_does_not_retrace_on_reordered_programs():
     with counters.watch() as w2:
         netsim.simulate_sweep(cfg, netsim.make_sweep(cfg, seed=[1, 2]))
     assert w2.iter_records == {"bounded": 0, "default": 1}
+
+
+# ---------------------------------------------------------------------------
+# resolve_plan predicts the groups run_plan dispatches
+# ---------------------------------------------------------------------------
+
+def _static_mix_plan():
+    """Static-factor and adaptive points under a favoritism the fused kernel
+    does not implement: with the kernel on, factor presence splits them."""
+    def build(pt):
+        cfg = _cfg(sim_time=0.02, use_pallas_kernel=True,
+                   protocol=_proto(favoritism="smallest_data_remaining"))
+        if pt["scheme"] == "static":
+            cfg = dataclasses.replace(cfg,
+                                      static_job_factors=np.array([1.0, 0.5]))
+        return cfg
+    return netsim.Plan(name="static-mix", build=build,
+                       axes=(netsim.Axis("scheme", ("adaptive", "static")),
+                             netsim.Axis("seed", (0, 1))))
+
+
+DISPATCH_PLANS = {
+    "padded_jobs": lambda: _jobs_plan(job_counts=(2, 3, 4), seeds=(3, 4),
+                                      sim_time=0.05, name="dispatch-jobs"),
+    "faults": _record_plan_triangle,
+    "static_mix": _static_mix_plan,
+}
+
+
+@pytest.mark.parametrize("case", sorted(DISPATCH_PLANS))
+def test_run_plan_dispatches_resolve_plan_groups(case):
+    """`run_plan` runs the groups `resolve_plan` predicts, group by group:
+    the same signature, the same K and the same iteration record."""
+    from repro.analysis import kernel_expectation
+
+    plan = DISPATCH_PLANS[case]()
+    _, cfgs, overrides, groups = experiment.resolve_plan(plan)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")     # the adaptive arm's fallback
+        pr = netsim.run_plan(plan, shard=False)
+    assert pr.group_errors == []
+    assert pr.n_compile_groups == len(groups) == len(pr.profile.groups)
+    for g, prof in zip(groups, pr.profile.groups):
+        assert prof.signature == experiment._group_signature(g)
+        assert prof.n_points == len(g.idxs)
+        assert prof.iter_slots == g.cfg.max_iters_recorded
+    if case == "static_mix":
+        lowerings = [kernel_expectation(
+            g.cfg, experiment.group_sweep(cfgs, overrides, g))
+            for g in groups]
+        assert lowerings == ["fallback", "fused"]

@@ -4,7 +4,6 @@ pinning, and the plan-layer plumbing (telemetry=, phase profile, cache
 versioning, per-plan fallback-warning reset)."""
 import dataclasses
 import math
-import os
 
 import jax
 import numpy as np
@@ -277,22 +276,6 @@ def test_profile_split_fields():
     assert s["n_groups"] == 1 and "compile_s" in s and "trace_s" in s
     assert s["stack_s"] > 0 and s["postprocess_s"] > 0
     assert prof.total_ticks == 2 * 2500
-
-
-def test_cache_versioned_and_pruned(tmp_path):
-    cache = str(tmp_path)
-    # stale v1-layout and torn entries must be evicted, current kept
-    open(os.path.join(cache, "0123abcd.pkl"), "wb").close()
-    open(os.path.join(cache, "v2-torn.pkl.tmp"), "wb").close()
-    pr = netsim.run_plan(_mini_plan(), cache_dir=cache)
-    assert pr.n_cache_hits == 0
-    fresh = [n for n in os.listdir(cache) if n.endswith(".pkl")
-             and n.startswith("v2-")]
-    assert len(fresh) == 2
-    assert netsim.prune_cache(cache) == 2
-    assert sorted(os.listdir(cache)) == sorted(fresh)
-    pr2 = netsim.run_plan(_mini_plan(), cache_dir=cache)
-    assert pr2.n_cache_hits == 2 and pr2.n_compile_groups == 0
 
 
 def test_fallback_warning_rearmed_per_plan():
