@@ -38,8 +38,10 @@ and `run_plan` does the partitioning (DESIGN.md §5):
      trace, one compile, K simulations per group — optionally sharding K
      across local devices; the group's iteration record is sized to the
      most iterations its points can finish (`_bound_record`);
-  5. post-process each point with its own (unpadded) config and attach a
-     `SweepPoint`, so every `SimResult` names its axis coordinates.
+  5. bring each group's output to the host in one fetch
+     (`metrics.host_view`), post-process each point with its own
+     (unpadded) config and attach a `SweepPoint`, so every `SimResult`
+     names its axis coordinates.
 
 A Fig. 10-style plan (7 job counts x 3 seeds x {OFF, WI}) thus compiles
 *two* programs (one per variant) instead of 14+, and the straggler /
@@ -540,8 +542,10 @@ class GroupProfile:
     (``stack_s``, ``run_plan.stack``), the device call with its
     ``block_until_ready`` (``wall_s``, ``run_plan.device``; trace and
     compile included when the group traced a new program, ``traced``) and
-    the per-point postprocess (``postprocess_s``, ``run_plan.postprocess``).  ``iter_slots`` is the length of the
-    group's iteration record.
+    the postprocess (``postprocess_s``, ``run_plan.postprocess``): the
+    group's one `metrics.host_view` fetch, then each point's
+    `metrics.postprocess` on NumPy slices of it.  ``iter_slots`` is the
+    length of the group's iteration record.
     """
 
     n_points: int                     # K lowered onto the sweep axis
@@ -554,6 +558,7 @@ class GroupProfile:
     stack_s: float = 0.0
     postprocess_s: float = 0.0
     iter_slots: int = 0
+    fetched_bytes: int = 0              # bytes the group's host_view fetched
 
 
 @dataclasses.dataclass
@@ -581,15 +586,17 @@ class PlanProfile:
         return sum(g.n_points * g.n_ticks for g in self.groups)
 
     def summary(self) -> dict:
+        """Counts and phase seconds of the call, the seconds rounded to the
+        microsecond: a group's postprocess can take under a millisecond."""
         out = {"n_groups": len(self.groups),
-               "wall_s": round(self.total_wall_s, 3),
+               "wall_s": round(self.total_wall_s, 6),
                "n_traced": sum(g.traced for g in self.groups),
-               "prepare_s": round(self.prepare_s, 3),
-               "stack_s": round(sum(g.stack_s for g in self.groups), 3),
+               "prepare_s": round(self.prepare_s, 6),
+               "stack_s": round(sum(g.stack_s for g in self.groups), 6),
                "postprocess_s": round(sum(g.postprocess_s
-                                          for g in self.groups), 3)}
+                                          for g in self.groups), 6)}
         for kind, secs in self.jit_s.items():
-            out[f"{kind}_s"] = round(secs, 3)
+            out[f"{kind}_s"] = round(secs, 6)
         return out
 
 
@@ -833,12 +840,15 @@ def run_plan(plan: Plan, *, shard="auto", pad_jobs: bool = True,
                 plan_profile.groups.append(prof)
                 with counters.span("run_plan.postprocess", group=gi,
                                    points=k) as post:
+                    view = metrics.host_view(group.cfg, raw)
+                    prof.fetched_bytes = sum(
+                        x.nbytes for x in jax.tree_util.tree_leaves(view))
                     for slot, i in enumerate(idxs):
                         point = SweepPoint(axes=dict(points[i]),
                                            params=per_point[slot],
                                            n_jobs=cfgs[i].jobs.n_jobs)
                         raw_i = jax.tree_util.tree_map(
-                            lambda x, s=slot: x[s], raw)
+                            lambda x, s=slot: x[s], view)
                         results[i] = metrics.postprocess(cfgs[i], raw_i,
                                                          point=point,
                                                          n_jobs=point.n_jobs)

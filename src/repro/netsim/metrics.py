@@ -93,20 +93,37 @@ def postprocess(cfg: SimConfig, raw: RawSimOutput,
     )
 
 
+def host_view(cfg: SimConfig, raw: RawSimOutput) -> RawSimOutput:
+    """A `simulate_sweep` output brought to the host in one `jax.device_get`.
+
+    Fetches only the leaves `postprocess` reads: the iteration record and
+    its counts, the ``trace_*`` series but ``trace_ratio``, and the
+    ``telemetry`` state when ``cfg.telemetry`` arms it.  ``final_state`` and
+    ``trace_ratio`` stay on the device and are None in the view.  A point's
+    output is then a NumPy slice of each leaf (``leaf[slot]``), where
+    slicing the device arrays would dispatch an index transfer, a slice and
+    a squeeze per leaf and point.
+    """
+    return jax.device_get(raw._replace(
+        trace_ratio=None, final_state=None,
+        telemetry=None if cfg.telemetry is None else raw.telemetry))
+
+
 def postprocess_sweep(cfg: SimConfig, raw: RawSimOutput,
                       points: Optional[list[SweepPoint]] = None
                       ) -> list[SimResult]:
     """Post-process a `simulate_sweep` output (leading [K] sweep axis) into
-    one SimResult per grid point, in sweep order.
+    one SimResult per grid point, in sweep order, from its `host_view`.
 
     Pass the `SweepPoint` list from `grid_sweep` (or hand-built labels) and
     each result carries its own point — downstream grouping then selects by
     axis value instead of relying on positional alignment.
     """
-    k = int(np.asarray(raw.iter_counts).shape[0])
+    view = host_view(cfg, raw)
+    k = view.iter_counts.shape[0]
     if points is not None and len(points) != k:
         raise ValueError(f"{len(points)} points for a K={k} sweep")
-    return [postprocess(cfg, jax.tree_util.tree_map(lambda x, i=i: x[i], raw),
+    return [postprocess(cfg, jax.tree_util.tree_map(lambda x, i=i: x[i], view),
                         point=None if points is None else points[i],
                         n_jobs=None if points is None else points[i].n_jobs)
             for i in range(k)]

@@ -8,11 +8,13 @@ every result is self-describing via its `SweepPoint`.
 import dataclasses
 import warnings
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import netsim
-from repro.netsim import engine, experiment
+from repro.netsim import engine, experiment, metrics
 from repro.core import Algo, CCParams, MLTCPConfig, Variant
 
 DT = 2e-5
@@ -524,3 +526,121 @@ def test_run_plan_dispatches_resolve_plan_groups(case):
             g.cfg, experiment.group_sweep(cfgs, overrides, g))
             for g in groups]
         assert lowerings == ["fallback", "fused"]
+
+
+# ---------------------------------------------------------------------------
+# Each group's output reaches the host in one fetch
+# ---------------------------------------------------------------------------
+
+def _capture_sweeps(monkeypatch) -> list:
+    """Record every group output `run_plan` dispatches."""
+    raws = []
+
+    def capture(cfg, sweep):
+        raws.append(engine.simulate_sweep(cfg, sweep))
+        return raws[-1]
+    monkeypatch.setattr(experiment, "simulate_sweep", capture)
+    return raws
+
+
+HOST_VIEW_PLANS = {
+    "padded_jobs": (lambda: _jobs_plan(job_counts=(2, 3), seeds=(3, 4),
+                                       sim_time=0.05, name="view-jobs"),
+                    None),
+    "telemetry": (lambda: _jobs_plan(job_counts=(2, 3), seeds=(3,),
+                                     sim_time=0.05, name="view-telemetry"),
+                  netsim.TelemetrySpec(stride=25)),
+    "faults": (_record_plan_triangle, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HOST_VIEW_PLANS))
+def test_host_view_results_match_device_slicing_bytewise(case, monkeypatch):
+    """Every `SimResult` field of `run_plan` is byte for byte what slicing
+    each point out of the same group output on the device gives."""
+    make, spec = HOST_VIEW_PLANS[case]
+    plan = make()
+    points, cfgs, overrides, groups = experiment.resolve_plan(
+        plan, telemetry=spec)
+    raws = _capture_sweeps(monkeypatch)
+    pr = netsim.run_plan(plan, shard=False, telemetry=spec)
+    assert pr.group_errors == [] and len(raws) == len(groups)
+    for g, raw in zip(groups, raws):
+        per_point = experiment._stack_group(cfgs, overrides, g)[0]
+        for slot, i in enumerate(g.idxs):
+            n = cfgs[i].jobs.n_jobs
+            point = netsim.SweepPoint(axes=dict(points[i]),
+                                      params=per_point[slot], n_jobs=n)
+            want = metrics.postprocess(
+                cfgs[i], jax.tree_util.tree_map(lambda x: x[slot], raw),
+                point=point, n_jobs=n)
+            _same_bytes(pr[i], want)
+            assert (pr[i].telemetry is not None) == (spec is not None)
+
+
+def test_postprocess_sweep_makes_no_implicit_transfer():
+    """A group's results come from one explicit `device_get`: with implicit
+    transfers refused, `postprocess_sweep` still runs, telemetry armed, where
+    indexing each point out of the device arrays would transfer its index."""
+    cfg = _cfg(sim_time=0.05, telemetry=netsim.TelemetrySpec(stride=25))
+    raw = jax.block_until_ready(netsim.simulate_sweep(
+        cfg, netsim.make_sweep(cfg, seed=[1, 2])))
+    with jax.transfer_guard("disallow"):
+        results = netsim.postprocess_sweep(cfg, raw)
+        view = metrics.host_view(cfg, raw)
+    assert len(results) == 2
+    assert all(r.telemetry is not None for r in results)
+    assert view.final_state is None and view.trace_ratio is None
+    assert all(isinstance(x, np.ndarray)
+               for x in jax.tree_util.tree_leaves(view))
+
+
+# the leaves of a group's output that `metrics.postprocess` reads
+READ_LEAVES = ("iter_times", "iter_counts", "trace_t", "trace_util",
+               "trace_incomm", "trace_drops", "trace_marks", "trace_jobtput")
+
+
+def test_fetched_bytes_are_the_leaves_postprocess_reads(monkeypatch):
+    """At the benchmark cell's group shapes (K = 4, seven jobs of eight
+    sockets on one link, 400 chunks, 256 record slots) each group fetches
+    the eight leaves `postprocess` reads, 110,384 B, and no `final_state`.
+    The device call is replaced by zeros of the program's output shapes."""
+    n_ticks, n_jobs = 148_032, 7
+
+    def build(pt):
+        variant = {"OFF": Variant.OFF, "WI": Variant.WI}[pt["variant"]]
+        return netsim.SimConfig(
+            topo=netsim.dumbbell(n_jobs, sockets_per_job=8),
+            jobs=netsim.JobSpec.simple([0.05] * n_jobs, [1e8] * n_jobs),
+            protocol=_proto(variant=variant), sim_time=n_ticks * DT, dt=DT,
+            seed=0)
+    plan = netsim.Plan(name="cell-shapes", build=build,
+                       axes=(netsim.Axis("variant", ("OFF", "WI")),
+                             netsim.Axis("seed", (1, 2, 3, 4))))
+    raws, fetched = [], []
+
+    def zeros(cfg, sweep):
+        shapes = jax.eval_shape(lambda s: engine.simulate_sweep(cfg, s),
+                                sweep)
+        raws.append(jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), shapes))
+        return raws[-1]
+
+    def device_get(tree):
+        fetched.append(tree)
+        return real_get(tree)
+    real_get = jax.device_get
+    monkeypatch.setattr(experiment, "simulate_sweep", zeros)
+    monkeypatch.setattr(experiment, "_bound_record", lambda cfg, sweep:
+                        dataclasses.replace(cfg, max_iters_recorded=256))
+    monkeypatch.setattr(jax, "device_get", device_get)
+    pr = netsim.run_plan(plan, shard=False)
+    assert [g.n_points for g in pr.profile.groups] == [4, 4]
+    assert len(fetched) == len(raws) == 2
+    for prof, raw, tree in zip(pr.profile.groups, raws, fetched):
+        read = sum(getattr(raw, name).nbytes for name in READ_LEAVES)
+        assert prof.fetched_bytes == read == 110_384
+        assert tree.final_state is None and tree.trace_ratio is None
+        assert tree.telemetry is None
+        assert sum(x.nbytes for x in jax.tree_util.tree_leaves(tree)) == read
+        assert sum(x.nbytes for x in jax.tree_util.tree_leaves(raw)) > read
